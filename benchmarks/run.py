@@ -18,6 +18,8 @@ def main(argv=None) -> None:
                     help="smaller request counts for CI")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import paper_figures, tiered_runtime_bench
 
     print("# --- paper figures/tables (TL-DRAM reproduction) ---")

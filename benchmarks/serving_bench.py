@@ -415,4 +415,6 @@ def run_all(out_path: str | None = "BENCH_serving.json"):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run_all()

@@ -8,7 +8,7 @@ package makes those checks a reusable static-analysis pass framework, the
 way TL-DRAM's isolation-transistor scheme only works because segment-access
 discipline is enforced mechanically, not by convention (PAPER.md):
 
-  walker    : recursive jaxpr traversal that handles pjit / scan / while /
+  walker    : recursive jaxpr traversal that handles jit / scan / while /
               cond / closed_call / custom_* / pallas_call nesting uniformly,
               collecting every equation with shapes, dtypes and a raw-KV
               taint lattice, plus HLO lowering/op-presence helpers

@@ -7,7 +7,7 @@ re-implemented sub-jaxpr discovery with bespoke handling of nested
 module centralizes both:
 
   * ``collect_eqns``: one pre-order traversal yields EVERY equation of a
-    program — through ``pjit``, ``scan``, ``while``, ``cond``,
+    program — through ``jit``, ``scan``, ``while``, ``cond``,
     ``closed_call``, ``custom_jvp/vjp_call``, ``remat`` and
     ``pallas_call`` — annotated with output/input avals, call-stack path,
     and a raw-KV taint bit;
@@ -128,7 +128,7 @@ def _call_taint_map(eqn, sub: jex_core.Jaxpr,
         if len(src) == len(sub_in):
             return dict(zip(sub_in, src))
     elif len(in_t) == len(sub_in):
-        # pjit / scan / closed_call / custom_* : 1:1 by construction
+        # jit / scan / closed_call / custom_* : 1:1 by construction
         # (scan: consts + carry + xs in both frames)
         return dict(zip(sub_in, in_t))
     elif len(in_t) <= len(sub_in):
@@ -208,7 +208,7 @@ def collect_eqns(jaxpr, kv_invars: Iterable[int] = (),
                      for v in sub_j.outvars])
             # output taint: transparent prims keep RAW alive; call-like
             # prims read it back from their sub-jaxpr's outvars (1:1 for
-            # pjit/scan/closed_call/custom_*; cond takes the max across
+            # jit/scan/closed_call/custom_*; cond takes the max across
             # branches; the while body's carry IS the eqn output); other
             # arithmetic degrades the max input taint to DERIVED
             exact = [ts for ts in sub_out_taints

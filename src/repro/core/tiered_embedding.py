@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.tier import TierCosts, ema_update
 from repro.tier.jax_engine import (apply_promotions, plan_promotions,
                                    preload_static)
+from repro.kernels import interpret_mode
 from repro.kernels.tiered_gather import tiered_gather
 
 DEFAULT_COSTS = TierCosts(near_cost=1.0, far_cost=4.0, migrate_cost=6.0,
@@ -61,7 +62,7 @@ def lookup(table: jax.Array, state: dict, tokens: jax.Array,
     kernel; misses take the HBM gather (far path).
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = interpret_mode()
     shape = tokens.shape
     flat = tokens.reshape(-1)
     slots = state["slot_of_token"][flat]

@@ -51,7 +51,7 @@ import numpy as np
 from repro.tier import TierCosts, ema_update
 from repro.tier.jax_engine import (apply_promotions, plan_promotions,
                                    preload_static)
-from repro.kernels import ops, ref
+from repro.kernels import interpret_mode, ops, ref
 
 # Cost model (napkin math, documented in docs/experiments.md): far pages are
 # gather-addressed — effective HBM bandwidth for 2KB-grain gathers is ~1/4 of
@@ -200,9 +200,8 @@ def tiered_attention(cache: dict, q: jax.Array, pos: jax.Array,
 
 def _near_stats(q, cache, near_len, cfg: TieredKVConfig):
     from repro.kernels.tiered_attention import near_decode_attention
-    interpret = jax.default_backend() == "cpu"
     return near_decode_attention(q, cache["near_k"], cache["near_v"],
-                                 near_len, interpret=interpret)
+                                 near_len, interpret=interpret_mode())
 
 
 def _far_stats(q, k, v, live_mask):
@@ -566,10 +565,9 @@ def paged_far_view(cache: dict, cfg: TieredKVConfig):
         # (the XLA path below clamps instead — either way, unmapped content
         # is arbitrary and masked)
         from repro.kernels.paged_gather import paged_gather
-        interpret = jax.default_backend() == "cpu"
-        far_k = paged_gather(cache["pool_k"], pt, interpret=interpret,
+        far_k = paged_gather(cache["pool_k"], pt, interpret=interpret_mode(),
                              mesh=cfg.mesh)
-        far_v = paged_gather(cache["pool_v"], pt, interpret=interpret,
+        far_v = paged_gather(cache["pool_v"], pt, interpret=interpret_mode(),
                              mesh=cfg.mesh)
         return far_k, far_v
     safe = jnp.maximum(pt, 0)
@@ -784,10 +782,9 @@ def paged_page_masses(q: jax.Array, cache: dict, pos: jax.Array,
     if cfg.fused_kernel:
         from repro.kernels.paged_masses import paged_masses
         walk = paged_score_walk(cache, pos, cfg)
-        interpret = jax.default_backend() == "cpu"
         mass = paged_masses(q, cache["pool_k"], walk["score_pid"],
                             walk["score_live"], walk["score_len"],
-                            interpret=interpret, mesh=cfg.mesh)   # (B, W)
+                            interpret=interpret_mode(), mesh=cfg.mesh)
         out = jnp.zeros((B, n_pages), jnp.float32).at[
             jnp.arange(B)[:, None], walk["score_j"]].add(mass, mode="drop")
         return out / max(H, 1)

@@ -1,10 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` — the
-kernel body runs in Python for correctness validation; on TPU the same code
-lowers to Mosaic.  ``tiered_decode_attention`` composes the near-tier Pallas
-kernel with the far-tier XLA path and the exact log-sum-exp merge — the
-two-tier read path of the TL-DRAM adaptation.
+On the CPU backend the kernels execute with ``interpret=True`` — the kernel
+body runs in Python for correctness validation; on TPU the same code lowers
+to Mosaic (``repro.kernels.interpret_mode``).  ``tiered_decode_attention``
+composes the near-tier Pallas kernel with the far-tier XLA path and the
+exact log-sum-exp merge — the two-tier read path of the TL-DRAM
+adaptation.
 """
 
 from __future__ import annotations
@@ -14,15 +15,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode as _interpret
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.ssd_scan import ssd_chunk_scan
 from repro.kernels.tiered_attention import near_decode_attention
 from repro.kernels.tiered_gather import tiered_gather
-
-
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

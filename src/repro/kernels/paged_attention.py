@@ -8,30 +8,27 @@ through the isolation transistor: cost is paid per access, never per bit of
 the segment (PAPER.md §3).  This kernel applies that economics to the
 gather path itself:
 
-  grid (B, Hkv); per step the kernel
-    1. attends the shared NEAR buffer (VMEM-resident, `C` page panels)
-       under per-(slot, near-slot) live counts — the global near tier
-       serves every tenant of a promoted page with its own position mask;
-    2. walks the slot's compacted page-table WALK LIST with a
-       `fori_loop`, issuing ONE async pool->VMEM copy per *mapped,
-       non-promoted, live* page and online-softmaxing the page panel
-       under its partial-last-page live count.
+  grid (B,); per slot the kernel, for ALL KV heads at once,
+    1. attends the shared NEAR buffer (VMEM-resident: its block index never
+       changes, so it is fetched once per call, not once per slot) under
+       per-(slot, near-slot) live counts — the global near tier serves
+       every tenant of a promoted page with its own position mask;
+    2. walks the slot's compacted page-table WALK LIST with a `fori_loop`,
+       issuing ONE async pool->VMEM copy of the whole `(page, Hkv, hd)`
+       page (contiguous in HBM) per *mapped, non-promoted, live* page and
+       online-softmaxing it under its partial-last-page live count.
 
   Far bytes touched per step per slot == sum of live, non-promoted page
   rows — never `n_pages * page` (asserted end-to-end by the serving
   accounting in BENCH_serving.json).
 
-The walk list / near metadata (`core.tiered_kv.paged_step_metadata`) is a
-handful of small int arrays computed ONCE per decode step from
-`(page_table, slot_of_page, page_of_slot, lengths)` and passed to every
-layer — it rides in SMEM; nothing `(B, n_pages, C)`-shaped exists anywhere
-on the per-layer path.
-
-The pool lives in `ANY` memory (HBM): only the walked pages transit VMEM,
-via a per-page DMA into a `(page, hd)` scratch panel.  Production note: a
-double-buffered two-panel pipeline would hide the copy latency behind the
-panel matmul; the single-panel form keeps the walk logic auditable and is
-what the interpret-mode suite validates.
+Layout (docs/design.md §2e): the pool keeps its `(P, page, Hkv, hd)` layout,
+so the `(Hkv, hd)` minor dims of every block are whole arrays and satisfy the
+TPU's (8, 128) tiling rule at any head count.  Scores and the p·v update are
+VPU multiply + reduce over `hd` (lanes) and `page` (leading dim) in f32; the
+per-slot ints (walk list, live counts, near live counts) ride in SMEM via
+scalar prefetch.  Queries enter as `(B, g, Hkv, hd)` so each GQA group is one
+`(Hkv, hd)` panel; m/l leave lane-replicated as `(B, g, Hkv, 128)`.
 
 Returns *unnormalized* `(out, m, l)` online-softmax stats, the same
 contract as `kernels.tiered_attention`, so callers can LSE-merge with other
@@ -47,37 +44,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
+LANES = 128                     # m/l leave the kernel lane-replicated
 
 
 def paged_attention_stats(q: jax.Array, pool_k: jax.Array,
                           pool_v: jax.Array, near_k: jax.Array,
-                          near_v: jax.Array, meta: dict, mesh=None):
+                          near_v: jax.Array, meta: dict, mesh=None,
+                          layer=None):
     """Run the fused kernel from a ``paged_step_metadata`` dict — the one
     entry point both the serving decode step and the core read path /
-    verification probe share (interpret mode on CPU backends).
+    verification probe share.  ``layer``: with stacked ``(L, P, page, Hkv,
+    hd)`` pools, the layer whose pages the walk reads (see
+    ``paged_attention``).
 
     With a ``mesh`` whose 'model' axis divides Hkv, the pool and near
     buffers are KV-HEAD-SHARDED and the kernel runs under ``shard_map``:
     each device walks only its head slice of every mapped page (GSPMD
     cannot partition a ``pallas_call``, so the shard boundary is explicit).
-    The per-head math is untouched — the kernel's grid is ``(B, Hkv)`` and
-    no arithmetic crosses heads — so a tiled ``all_gather`` of the per-head
-    stats over 'model' returns REPLICATED (out, m, l) bit-identical to the
-    single-device call, and every cross-head reduction downstream (the wo
-    projection, the LSE merge) sees the full head dim in single-device
-    order.  Head counts that do not divide the axis fall back to the
-    replicated single-device call (``kv_shard_count``)."""
+    The per-head math is untouched — no arithmetic crosses heads — so a
+    tiled ``all_gather`` of the per-head stats over 'model' returns
+    REPLICATED (out, m, l) bit-identical to the single-device call, and
+    every cross-head reduction downstream (the wo projection, the LSE
+    merge) sees the full head dim in single-device order.  Head counts
+    that do not divide the axis fall back to the replicated single-device
+    call (``kv_shard_count``)."""
     from repro.sharding.specs import kv_shard_count
-    interpret = jax.default_backend() == "cpu"
+    walk = (meta["walk_pid"], meta["walk_live"], meta["walk_len"],
+            meta["near_live"])
     Hkv = pool_k.shape[-2]
     if kv_shard_count(mesh, Hkv) == 1:
-        return paged_attention(q, pool_k, pool_v, near_k, near_v,
-                               meta["walk_pid"], meta["walk_live"],
-                               meta["walk_len"], meta["near_live"],
-                               interpret=interpret)
+        return paged_attention(q, pool_k, pool_v, near_k, near_v, *walk,
+                               layer=layer, interpret=interpret_mode())
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     B, H, hd = q.shape
     g = H // Hkv
@@ -86,7 +87,7 @@ def paged_attention_stats(q: jax.Array, pool_k: jax.Array,
         Hl = pk.shape[-2]                       # this shard's kv heads
         out, m, l = paged_attention(q4.reshape(B, Hl * g, hd), pk, pv,
                                     nk, nv, w_pid, w_live, w_len, n_live,
-                                    interpret=interpret)
+                                    layer=layer, interpret=interpret_mode())
         gather = functools.partial(jax.lax.all_gather, axis_name="model",
                                    axis=1, tiled=True)
         return (gather(out.reshape(B, Hl, g, hd)).reshape(B, H, hd),
@@ -94,138 +95,150 @@ def paged_attention_stats(q: jax.Array, pool_k: jax.Array,
                 gather(l.reshape(B, Hl, g)).reshape(B, H))
 
     head = P(None, "model")                     # dim ndim-2 = Hkv
-    sharded = shard_map(
+    pool = P(*[None] * (pool_k.ndim - 2), "model")
+    sharded = jax.shard_map(
         local_stats, mesh=mesh,
-        in_specs=(P(None, "model"), P(None, None, "model"),
-                  P(None, None, "model"), head, head,
+        in_specs=(P(None, "model"), pool, pool, head, head,
                   P(), P(), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False)                        # pallas body: no rep tracking
+        check_vma=False)                        # pallas body: no vma tracking
     return sharded(q.reshape(B, Hkv, g, hd), pool_k, pool_v, near_k, near_v,
-                   meta["walk_pid"], meta["walk_live"], meta["walk_len"],
-                   meta["near_live"])
+                   *walk)
 
 
-def _paged_attention_kernel(h_ref, walk_pid_ref, walk_live_ref, walk_len_ref,
-                            near_live_ref, q_ref, nk_ref, nv_ref,
-                            pool_k_ref, pool_v_ref,
-                            o_ref, m_ref, l_ref,
-                            kbuf, vbuf, sem_k, sem_v, *,
-                            page: int, n_near: int, scale: float):
-    h = h_ref[0]                        # this grid step's KV head (SMEM iota:
-                                        # interpret mode lacks program_id)
-    q = q_ref[0, 0].astype(jnp.float32) * scale              # (g, hd)
-    g, hd = q.shape
-    row = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+def _paged_attention_kernel(layer_ref, walk_pid_ref, walk_live_ref,
+                            walk_len_ref, near_live_ref, q_ref, nk_ref,
+                            nv_ref, pool_k_ref, pool_v_ref, o_ref, m_ref,
+                            l_ref, kbuf, vbuf, sem, *, page: int,
+                            n_near: int, W: int, scale: float,
+                            stacked: bool):
+    b = pl.program_id(0)
+    if stacked:                         # (L, P, page, Hkv, hd) pools
+        pool_k_ref = pool_k_ref.at[layer_ref[0]]
+        pool_v_ref = pool_v_ref.at[layer_ref[0]]
+    _, g, Hkv, hd = q_ref.shape
+    qs = [q_ref[0, j].astype(jnp.float32) * scale for j in range(g)]
+    row = jax.lax.broadcasted_iota(jnp.int32, (page, Hkv, 1), 0)
 
     def update(carry, kp, vp, live):
-        """One page panel's online-softmax update; rows >= live are dead."""
-        acc, m, l = carry
-        s = jax.lax.dot_general(q, kp, (((1,), (1,)), ((), ())))  # (g, page)
+        """One page's online-softmax update for every (group, head);
+        rows >= live are dead.  kp/vp: (page, Hkv, hd) f32."""
         alive = row < live
-        s = jnp.where(alive, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(alive, p, 0.0)
-        l_new = l * alpha + p.sum(axis=1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, vp, (((1,), (0,)), ((), ())))
-        return acc_new, m_new, l_new
+        new = []
+        for j in range(g):
+            acc, m, l = carry[j]
+            s = jnp.sum(kp * qs[j][None], axis=-1, keepdims=True)
+            s = jnp.where(alive, s, NEG_INF)                 # (page, Hkv, 1)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0))       # (Hkv, 1)
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(alive, jnp.exp(s - m_new[None]), 0.0)
+            new.append((acc * alpha + jnp.sum(p * vp, axis=0),
+                        m_new, l * alpha + jnp.sum(p, axis=0)))
+        return tuple(new)
 
-    acc = jnp.zeros((g, hd), jnp.float32)
-    m = jnp.full((g, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((g, 1), jnp.float32)
+    carry = tuple((jnp.zeros((Hkv, hd), jnp.float32),
+                   jnp.full((Hkv, 1), NEG_INF, jnp.float32),
+                   jnp.zeros((Hkv, 1), jnp.float32)) for _ in range(g))
 
-    # -- near pass: C resident panels, dense in VMEM --------------------------
+    # -- near pass: C resident pages, dense in VMEM ---------------------------
     def near_body(c, carry):
-        kp = nk_ref[pl.ds(c * page, page), 0, :].astype(jnp.float32)
-        vp = nv_ref[pl.ds(c * page, page), 0, :].astype(jnp.float32)
-        return update(carry, kp, vp, near_live_ref[0, c])
+        rows = pl.ds(c * page, page)
+        return update(carry, nk_ref[rows].astype(jnp.float32),
+                      nv_ref[rows].astype(jnp.float32),
+                      near_live_ref[b * n_near + c])
 
-    acc, m, l = jax.lax.fori_loop(0, n_near, near_body, (acc, m, l))
+    carry = jax.lax.fori_loop(0, n_near, near_body, carry)
 
     # -- far pass: walk the slot's live, non-promoted pages -------------------
     def far_body(i, carry):
-        pid = walk_pid_ref[0, i]
-        cp_k = pltpu.make_async_copy(pool_k_ref.at[pid, :, h], kbuf, sem_k)
-        cp_v = pltpu.make_async_copy(pool_v_ref.at[pid, :, h], vbuf, sem_v)
+        pid = walk_pid_ref[b * W + i]
+        cp_k = pltpu.make_async_copy(pool_k_ref.at[pid], kbuf, sem.at[0])
+        cp_v = pltpu.make_async_copy(pool_v_ref.at[pid], vbuf, sem.at[1])
         cp_k.start()
         cp_v.start()
         cp_k.wait()
         cp_v.wait()
         return update(carry, kbuf[...].astype(jnp.float32),
-                      vbuf[...].astype(jnp.float32), walk_live_ref[0, i])
+                      vbuf[...].astype(jnp.float32),
+                      walk_live_ref[b * W + i])
 
-    acc, m, l = jax.lax.fori_loop(0, walk_len_ref[0], far_body, (acc, m, l))
+    carry = jax.lax.fori_loop(0, walk_len_ref[b], far_body, carry)
 
-    o_ref[0, 0] = acc
-    m_ref[0, 0] = m[:, 0]
-    l_ref[0, 0] = l[:, 0]
+    for j in range(g):
+        acc, m, l = carry[j]
+        o_ref[0, j] = acc
+        m_ref[0, j] = jnp.broadcast_to(m, (Hkv, LANES))
+        l_ref[0, j] = jnp.broadcast_to(l, (Hkv, LANES))
 
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                     near_k: jax.Array, near_v: jax.Array,
                     walk_pid: jax.Array, walk_live: jax.Array,
                     walk_len: jax.Array, near_live: jax.Array,
-                    interpret: bool = False):
+                    layer=None, interpret: bool = False):
     """Fused two-tier paged decode attention.
 
     q: (B, H, hd) single-token queries (GQA: H a multiple of Hkv).
-    pool_k/pool_v: (P, page, Hkv, hd) shared far pool (stays in HBM/ANY).
-    near_k/near_v: (C*page, Hkv, hd) global near buffer (VMEM-streamed).
+    pool_k/pool_v: (P, page, Hkv, hd) shared far pool (stays in HBM/ANY),
+      or the stacked per-layer pools (L, P, page, Hkv, hd) with ``layer``
+      the (traced) layer to read — the layer scan of the decode step hands
+      the whole pool in, so no per-layer pool slice is ever copied.
+    near_k/near_v: (C*page, Hkv, hd) global near buffer (VMEM-resident).
     walk_pid/walk_live: (B, W) int32 — per slot, the pool ids of its mapped,
       non-promoted, live pages (front-packed) and each page's live row
       count (partial-last-page mask); entries past ``walk_len[b]`` unused.
     walk_len: (B,) int32.  near_live: (B, C) int32 — per (slot, near-slot)
-      live rows (0 masks the whole panel, serving non-tenants and empties).
+      live rows (0 masks the whole page, serving non-tenants and empties).
 
     Returns (out (B,H,hd) f32 unnormalized, m (B,H) f32, l (B,H) f32).
     """
     B, H, hd = q.shape
-    P, page, Hkv, _ = pool_k.shape
+    page, Hkv = pool_k.shape[-3:-1]
+    stacked = pool_k.ndim == 5
+    assert stacked == (layer is not None), "layer goes with stacked pools"
     g = H // Hkv
     n_near = near_k.shape[0] // page
     W = walk_pid.shape[1]
-    q4 = q.reshape(B, Hkv, g, hd)
-    heads = jnp.arange(Hkv, dtype=jnp.int32)
-    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    flat = lambda a: jnp.asarray(a, jnp.int32).reshape(-1)
+    # (B, g, Hkv, hd): each GQA group is one (Hkv, hd) panel
+    qg = q.reshape(B, Hkv, g, hd).transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_paged_attention_kernel, page=page,
-                               n_near=n_near, scale=hd ** -0.5)
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+                               n_near=n_near, W=W, scale=hd ** -0.5,
+                               stacked=stacked)
+    per_slot = lambda b, *_: (b, 0, 0, 0)
+    resident = lambda b, *_: (0, 0, 0)
     out, m, l = pl.pallas_call(
         kernel,
-        grid=(B, Hkv),
-        in_specs=[
-            smem((1,), lambda b, h: (h,)),
-            smem((1, W), lambda b, h: (b, 0)),
-            smem((1, W), lambda b, h: (b, 0)),
-            smem((1,), lambda b, h: (b,)),
-            smem((1, n_near), lambda b, h: (b, 0)),
-            pl.BlockSpec((1, 1, g, hd), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((n_near * page, 1, hd), lambda b, h: (0, h, 0)),
-            pl.BlockSpec((n_near * page, 1, hd), lambda b, h: (0, h, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda b, h: (b, h, 0)),
-            pl.BlockSpec((1, 1, g), lambda b, h: (b, h, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, g, Hkv, hd), per_slot),
+                pl.BlockSpec((n_near * page, Hkv, hd), resident),
+                pl.BlockSpec((n_near * page, Hkv, hd), resident),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, g, Hkv, hd), per_slot),
+                pl.BlockSpec((1, g, Hkv, LANES), per_slot),
+                pl.BlockSpec((1, g, Hkv, LANES), per_slot),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((page, Hkv, hd), pool_k.dtype),
+                pltpu.VMEM((page, Hkv, hd), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, g), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, g), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((page, hd), pool_k.dtype),
-            pltpu.VMEM((page, hd), pool_v.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
+            jax.ShapeDtypeStruct((B, g, Hkv, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, g, Hkv, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, g, Hkv, LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(heads, i32(walk_pid), i32(walk_live), i32(walk_len), i32(near_live),
-      q4, near_k, near_v, pool_k, pool_v)
-    return (out.reshape(B, H, hd), m.reshape(B, H), l.reshape(B, H))
+    )(flat(0 if layer is None else layer), flat(walk_pid), flat(walk_live),
+      flat(walk_len), flat(near_live), qg, near_k, near_v, pool_k, pool_v)
+    heads = lambda a: a.transpose(0, 2, 1, *range(3, a.ndim))
+    return (heads(out).reshape(B, H, hd),
+            heads(m[..., 0]).reshape(B, H),
+            heads(l[..., 0]).reshape(B, H))

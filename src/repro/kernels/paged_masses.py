@@ -6,13 +6,15 @@ kernel (`kernels.paged_attention`) eliminated from the read path — just to
 softmax it and sum per page.  With the pool as the single source of truth
 (ISSUE 5) scoring walks the page table the same way the read does:
 
-  grid (B, Hkv); per step the kernel walks the slot's SCORE walk list
-  (ALL mapped, live pages — near-resident pages included, so retention
-  scores stay fresh; contrast the read walk, which skips promoted pages),
-  issuing ONE async pool->VMEM copy per page and accumulating an
-  online-softmax numerator PER WALK ENTRY (a (g, W) accumulator rescaled
-  by the running max), so the per-page probability masses come out of one
-  pass with no (B, T) score tensor and no far-view materialization.
+  grid (B,); per slot the kernel walks the slot's SCORE walk list (ALL
+  mapped, live pages — near-resident pages included, so retention scores
+  stay fresh; contrast the read walk, which skips promoted pages), issuing
+  ONE async pool->VMEM copy of the whole (page, Hkv, hd) K page per entry
+  and accumulating, for every KV head at once, an online-softmax numerator
+  PER WALK ENTRY (an (Hkv, W) accumulator per GQA group, rescaled by the
+  running max), so the per-page probability masses come out of one pass
+  with no (B, T) score tensor and no far-view materialization.  Layout and
+  tiling follow `kernels.paged_attention`.
 
 Only ``pool_k`` is touched — masses need scores, not values — so the
 scoring pass moves half the bytes of even a hypothetical fused read over
@@ -36,38 +38,42 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_masses_kernel(h_ref, pid_ref, live_ref, len_ref, q_ref,
-                         pool_k_ref, o_ref, kbuf, sem_k, *,
-                         page: int, n_walk: int, scale: float):
-    h = h_ref[0]                       # this grid step's KV head (SMEM iota)
-    q = q_ref[0, 0].astype(jnp.float32) * scale               # (g, hd)
-    g, hd = q.shape
-    row = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+def _paged_masses_kernel(pid_ref, live_ref, len_ref, q_ref, pool_k_ref,
+                         o_ref, kbuf, sem, *, page: int, W: int,
+                         scale: float):
+    b = pl.program_id(0)
+    _, g, Hkv, hd = q_ref.shape
+    qs = [q_ref[0, j].astype(jnp.float32) * scale for j in range(g)]
+    row = jax.lax.broadcasted_iota(jnp.int32, (page, Hkv, 1), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Hkv, W), 1)
 
     def body(i, carry):
-        psum, m, l = carry
-        pid = pid_ref[0, i]
-        cp = pltpu.make_async_copy(pool_k_ref.at[pid, :, h], kbuf, sem_k)
+        cp = pltpu.make_async_copy(pool_k_ref.at[pid_ref[b * W + i]], kbuf,
+                                   sem)
         cp.start()
         cp.wait()
-        kp = kbuf[...].astype(jnp.float32)                    # (page, hd)
-        s = jax.lax.dot_general(q, kp, (((1,), (1,)), ((), ())))
-        alive = row < live_ref[0, i]
-        s = jnp.where(alive, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.where(alive, jnp.exp(s - m_new), 0.0)
-        l_new = l * alpha + p.sum(axis=1, keepdims=True)
-        # rescale every prior entry's numerator, then deposit this one's
-        psum_new = jax.lax.dynamic_update_slice(
-            psum * alpha, p.sum(axis=1, keepdims=True), (0, i))
-        return psum_new, m_new, l_new
+        kp = kbuf[...].astype(jnp.float32)                 # (page, Hkv, hd)
+        alive = row < live_ref[b * W + i]
+        new = []
+        for j in range(g):
+            psum, m, l = carry[j]
+            s = jnp.sum(kp * qs[j][None], axis=-1, keepdims=True)
+            s = jnp.where(alive, s, NEG_INF)                 # (page, Hkv, 1)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0))       # (Hkv, 1)
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(alive, jnp.exp(s - m_new[None]), 0.0)
+            mass = jnp.sum(p, axis=0)                        # (Hkv, 1)
+            # rescale every prior entry's numerator, then deposit this one's
+            new.append((psum * alpha + jnp.where(col == i, mass, 0.0),
+                        m_new, l * alpha + mass))
+        return tuple(new)
 
-    psum = jnp.zeros((g, n_walk), jnp.float32)
-    m = jnp.full((g, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((g, 1), jnp.float32)
-    psum, m, l = jax.lax.fori_loop(0, len_ref[0], body, (psum, m, l))
-    o_ref[0, 0] = (psum / jnp.maximum(l, 1e-30)).sum(axis=0)
+    carry = tuple((jnp.zeros((Hkv, W), jnp.float32),
+                   jnp.full((Hkv, 1), NEG_INF, jnp.float32),
+                   jnp.zeros((Hkv, 1), jnp.float32)) for _ in range(g))
+    carry = jax.lax.fori_loop(0, len_ref[b], body, carry)
+    total = sum(psum / jnp.maximum(l, 1e-30) for psum, _, l in carry)
+    o_ref[0] = jnp.sum(total, axis=0, keepdims=True)         # (1, W)
 
 
 def paged_masses(q: jax.Array, pool_k: jax.Array, score_pid: jax.Array,
@@ -93,12 +99,11 @@ def paged_masses(q: jax.Array, pool_k: jax.Array, score_pid: jax.Array,
     order — masses drive page *placement* only, and emitted tokens are
     placement-invariant (the policy-parity pin)."""
     from repro.sharding.specs import kv_shard_count
-    if mesh is not None and kv_shard_count(mesh, pool_k.shape[-2]) > 1:
-        from jax.experimental.shard_map import shard_map
+    B, H, hd = q.shape
+    _, page, Hkv, _ = pool_k.shape
+    g = H // Hkv
+    if mesh is not None and kv_shard_count(mesh, Hkv) > 1:
         from jax.sharding import PartitionSpec as P_
-        B, H, hd = q.shape
-        Hkv = pool_k.shape[-2]
-        g = H // Hkv
 
         def local_masses(q4, pk, s_pid, s_live, s_len):
             Hl = pk.shape[-2]
@@ -106,45 +111,39 @@ def paged_masses(q: jax.Array, pool_k: jax.Array, score_pid: jax.Array,
                                s_len, interpret=interpret)
             return jax.lax.psum(out, "model")
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             local_masses, mesh=mesh,
             in_specs=(P_(None, "model"), P_(None, None, "model"),
                       P_(), P_(), P_()),
             out_specs=P_(),
-            check_rep=False)
+            check_vma=False)
         return sharded(q.reshape(B, Hkv, g, hd), pool_k, score_pid,
                        score_live, score_len)
-    B, H, hd = q.shape
-    P, page, Hkv, _ = pool_k.shape
-    g = H // Hkv
     W = score_pid.shape[1]
-    q4 = q.reshape(B, Hkv, g, hd)
-    heads = jnp.arange(Hkv, dtype=jnp.int32)
-    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    flat = lambda a: jnp.asarray(a, jnp.int32).reshape(-1)
+    # (B, g, Hkv, hd): each GQA group is one (Hkv, hd) panel
+    qg = q.reshape(B, Hkv, g, hd).transpose(0, 2, 1, 3)
 
-    kernel = functools.partial(_paged_masses_kernel, page=page, n_walk=W,
+    kernel = functools.partial(_paged_masses_kernel, page=page, W=W,
                                scale=hd ** -0.5)
-    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv),
-        in_specs=[
-            smem((1,), lambda b, h: (h,)),
-            smem((1, W), lambda b, h: (b, 0)),
-            smem((1, W), lambda b, h: (b, 0)),
-            smem((1,), lambda b, h: (b,)),
-            pl.BlockSpec((1, 1, g, hd), lambda b, h: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, 1, W), lambda b, h: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, W), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((page, hd), pool_k.dtype),
-            pltpu.SemaphoreType.DMA,
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, g, Hkv, hd), lambda b, *_: (b, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, 1, W), lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((page, Hkv, hd), pool_k.dtype),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
         interpret=interpret,
-    )(heads, i32(score_pid), i32(score_live), i32(score_len), q4, pool_k)
-    return out.sum(axis=1)
+    )(flat(score_pid), flat(score_live), flat(score_len), qg, pool_k)
+    return out[:, 0]
 
 
 def paged_masses_ref(q: jax.Array, pool_k: jax.Array, score_pid: jax.Array,

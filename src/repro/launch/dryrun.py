@@ -158,9 +158,7 @@ def lower_cell(arch_name: str, shape_name: str, multi_pod: bool,
 
     compile_s = time.time() - t0
     mem = compiled.memory_analysis()
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):      # jax 0.4.x returns [dict]; >=0.5 a dict
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     hlo = hlo_analysis.analyze_module(compiled.as_text())
 
     art = {

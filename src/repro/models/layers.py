@@ -172,7 +172,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                            near_k: jax.Array, near_v: jax.Array,
-                           meta: dict, mesh=None) -> jax.Array:
+                           meta: dict, mesh=None, layer=None) -> jax.Array:
     """Single-token attention through the fused paged tier (ISSUE 4).
 
     The TL-DRAM serving read path: instead of materializing the slot's far
@@ -182,7 +182,9 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     live counts.  ``meta`` is `core.tiered_kv.paged_step_metadata`, computed
     once per decode step and shared by every layer.
 
-    q: (B,1,H,hd); pool_k/pool_v: (P,page,Hkv,hd); near: (C*page,Hkv,hd).
+    q: (B,1,H,hd); pool_k/pool_v: (P,page,Hkv,hd), or the stacked
+    (L,P,page,Hkv,hd) pools with ``layer`` the layer to read;
+    near: (C*page,Hkv,hd).
     Returns (B,1,H,hd), exactly standard attention over the live prefix.
 
     ``mesh``: KV-head-sharded pool/near buffers — the kernel runs per head
@@ -192,7 +194,7 @@ def paged_decode_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     from repro.kernels import ref
     from repro.kernels.paged_attention import paged_attention_stats
     stats = paged_attention_stats(q[:, 0], pool_k, pool_v, near_k, near_v,
-                                  meta, mesh=mesh)
+                                  meta, mesh=mesh, layer=layer)
     return ref.merge_attention_stats([stats])[:, None].astype(q.dtype)
 
 

@@ -541,7 +541,7 @@ def paged_decode_step(params: Params, cache: Cache, batch: dict,
         mesh = None                   # GQA/MQA fallback: fully replicated
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P_
-        _pool_ns = NamedSharding(mesh, P_(None, None, "model"))
+        _pool_ns = NamedSharding(mesh, P_(None, None, None, "model"))
         _repl_ns = NamedSharding(mesh, P_())
     x = _embed_inputs(params, batch, arch).astype(compute_dtype)
     x = ctx.constrain(x, ctx.BATCH, ctx.SEQ, None)
@@ -553,37 +553,33 @@ def paged_decode_step(params: Params, cache: Cache, batch: dict,
     cparams = jax.tree.map(
         lambda a: a.astype(compute_dtype)
         if a.dtype == jnp.float32 and a.ndim > 1 else a, params["layers"])
-    # the near buffers are read-only per step: scan them as inputs but keep
-    # them OUT of the per-layer cache so the scan does not stack an
-    # untouched copy of both buffers every decode step
-    layer_cache = {k: v for k, v in cache.items()
-                   if k not in ("pos", "near_k", "near_v")}
+    n_pages = meta["pt"].shape[1]
+    safe = jnp.maximum(meta["pt"], 0)
 
-    def body(h, scanned):
-        layer_params, cl, nk, nv = scanned
+    def body(carry, scanned):
+        # the stacked pools ride in the CARRY and each layer writes its
+        # appended row in place (a donated pool is then updated without a
+        # second pool-sized buffer); the read-only near buffers are scanned
+        h, pool_k, pool_v = carry
+        layer_params, nk, nv, layer = scanned
 
-        def kv_hook(q, k, v, cl2):
-            pool_k = cl2["pool_k"].at[meta["append_pid"],
-                                      meta["append_off"]].set(k[:, 0],
-                                                              mode="drop")
-            pool_v = cl2["pool_v"].at[meta["append_pid"],
-                                      meta["append_off"]].set(v[:, 0],
-                                                              mode="drop")
+        def kv_hook(q, k, v, _):
+            at = (layer, meta["append_pid"], meta["append_off"])
+            pk = pool_k.at[at].set(k[:, 0], mode="drop")
+            pv = pool_v.at[at].set(v[:, 0], mode="drop")
             if mesh is not None:
                 # keep the appended pool head-sharded (the scatter touches
-                # only page/offset dims — GSPMD must not drift the pool to
-                # replicated across steps)
-                pool_k = jax.lax.with_sharding_constraint(pool_k, _pool_ns)
-                pool_v = jax.lax.with_sharding_constraint(pool_v, _pool_ns)
+                # only layer/page/offset dims — GSPMD must not drift the
+                # pool to replicated across steps)
+                pk = jax.lax.with_sharding_constraint(pk, _pool_ns)
+                pv = jax.lax.with_sharding_constraint(pv, _pool_ns)
             if fused:
-                out = paged_decode_attention(q, pool_k, pool_v, nk, nv,
-                                             meta, mesh=mesh)
+                out = paged_decode_attention(q, pk, pv, nk, nv, meta,
+                                             mesh=mesh, layer=layer)
             else:
-                n_pages = meta["pt"].shape[1]
-                safe = jnp.maximum(meta["pt"], 0)
-                _, page, Hkv, hd = pool_k.shape
-                k_view = pool_k[safe].reshape(B, n_pages * page, Hkv, hd)
-                v_view = pool_v[safe].reshape(B, n_pages * page, Hkv, hd)
+                _, _, page, Hkv, hd = pk.shape
+                k_view = pk[layer, safe].reshape(B, n_pages * page, Hkv, hd)
+                v_view = pv[layer, safe].reshape(B, n_pages * page, Hkv, hd)
                 out = decode_attention(q, k_view, v_view, pos)
                 if mesh is not None:
                     # per-head stats are exact under GSPMD (no op crosses
@@ -592,22 +588,26 @@ def paged_decode_step(params: Params, cache: Cache, batch: dict,
                     # instead of a GSPMD partial-sum psum — the dense
                     # path's bit-identity pin
                     out = jax.lax.with_sharding_constraint(out, _repl_ns)
-            return out, dict(pool_k=pool_k, pool_v=pool_v)
+            return out, {"pool_k": pk, "pool_v": pv}
 
         h = ctx.constrain(h, ctx.BATCH, ctx.SEQ, None)
-        h, new_cl, q = _block_decode(layer_params, h, cl, pos, arch,
-                                     kv_hook=kv_hook)
+        h, pools, q = _block_decode(layer_params, h, {}, pos, arch,
+                                    kv_hook=kv_hook)
         h = ctx.constrain(h, ctx.BATCH, ctx.SEQ, None)
-        return h, (new_cl, q if want_aux else None)
+        return (h, pools["pool_k"], pools["pool_v"]), \
+            q if want_aux else None
 
-    x, (new_layer_cache, qs) = jax.lax.scan(
-        body, x, (cparams, layer_cache, cache["near_k"], cache["near_v"]))
+    layers = jnp.arange(arch.n_layers, dtype=jnp.int32)
+    (x, pool_k, pool_v), qs = jax.lax.scan(
+        body, (x, cache["pool_k"], cache["pool_v"]),
+        (cparams, cache["near_k"], cache["near_v"], layers))
     x = rms_norm(x, params["final_norm"].astype(compute_dtype))
     logits = _lm_logits(params, x, arch)
     logits = ctx.constrain(logits, ctx.BATCH,
                            *([None] * (logits.ndim - 2)), ctx.MODEL)
-    new_cache = {**new_layer_cache, "near_k": cache["near_k"],
-                 "near_v": cache["near_v"], "pos": cache["pos"] + 1}
+    new_cache = {"pool_k": pool_k, "pool_v": pool_v,
+                 "near_k": cache["near_k"], "near_v": cache["near_v"],
+                 "pos": cache["pos"] + 1}
     if want_aux:
         aux = {"q0": qs[0][:, 0].astype(jnp.float32)}
         return logits, new_cache, aux

@@ -24,9 +24,7 @@ def compress_psum(grads, residual, axis_names: tuple[str, ...]):
     """
     n = 1
     for ax in axis_names:
-        # jax >= 0.5 has lax.axis_size; 0.4.x spells it psum(1, axis).
-        n *= (jax.lax.axis_size(ax) if hasattr(jax.lax, "axis_size")
-              else jax.lax.psum(1, ax))
+        n *= jax.lax.axis_size(ax)
 
     def leaf(g, r):
         g = g.astype(jnp.float32) + r

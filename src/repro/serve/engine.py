@@ -208,7 +208,11 @@ class ServingEngine:
                                         make_pool_chunk_prefill_step,
                                         make_pool_prefill_step,
                                         make_pool_suffix_prefill_step)
-        self._decode = jax.jit(make_paged_tiered_decode_step(arch, tier_cfg))
+        # every program that writes the pool DONATES it (and the decode
+        # step its whole KV cache): the engine rebinds the outputs at once,
+        # so the pool is updated in place instead of living twice in HBM
+        self._decode = jax.jit(make_paged_tiered_decode_step(arch, tier_cfg),
+                               donate_argnums=(1,))
         # per-step read metadata, computed ONCE per tick and shared by
         # every layer: lengths = pos + 1 (the appended token is live),
         # append routing from pos
@@ -253,16 +257,17 @@ class ServingEngine:
         # prompt-length bucket (and every matched-prefix length)
         self._prefill = jax.jit(
             make_pool_prefill_step(arch, cfg.max_len, tier_cfg.page,
-                                   mesh=self.mesh))
+                                   mesh=self.mesh), donate_argnums=(2, 3))
         self._prefill_sfx = jax.jit(
             make_pool_suffix_prefill_step(arch, cfg.max_len, tier_cfg.page,
-                                          mesh=self.mesh))
+                                          mesh=self.mesh),
+            donate_argnums=(4, 5))
         # chunk-resumable admission prefill: t_pre (the cursor) is static —
         # it sizes the in-jit prefix slice; jit caches per (t_pre, s_pad)
         self._prefill_chunk = jax.jit(
             make_pool_chunk_prefill_step(arch, cfg.max_len, tier_cfg.page,
                                          mesh=self.mesh),
-            static_argnames=("t_pre",))
+            static_argnames=("t_pre",), donate_argnums=(2, 3))
         page = tier_cfg.page
 
         def gather_prefix(pool_k, pool_v, ids):
@@ -704,23 +709,27 @@ class ServingEngine:
         # buffer.  No dense per-slot master exists anywhere in this engine.
         pshape = (arch.n_layers, self.pool_pages, cfg.tier.page,
                   arch.n_kv_heads, hd)
-        self.pool_k = jnp.zeros(pshape, dtype)
-        self.pool_v = jnp.zeros(pshape, dtype)
         nshape = (arch.n_layers, cfg.tier.near_pages * cfg.tier.page,
                   arch.n_kv_heads, hd)
-        self.near_k = jnp.zeros(nshape, dtype)
-        self.near_v = jnp.zeros(nshape, dtype)
+
+        def zeros():
+            return {"pool_k": jnp.zeros(pshape, dtype),
+                    "pool_v": jnp.zeros(pshape, dtype),
+                    "near_k": jnp.zeros(nshape, dtype),
+                    "near_v": jnp.zeros(nshape, dtype)}
+
         if self.kv_shards > 1:
-            # place the pool/near buffers on their KV-head sharding up
-            # front, so every jitted step consumes and produces the sharded
-            # layout instead of re-sharding on entry
-            kv_tree = {"pool_k": self.pool_k, "pool_v": self.pool_v,
-                       "near_k": self.near_k, "near_v": self.near_v}
-            named = to_named(cache_specs(kv_tree, arch, self.mesh),
-                             self.mesh)
-            placed = jax.device_put(kv_tree, named)
-            self.pool_k, self.pool_v = placed["pool_k"], placed["pool_v"]
-            self.near_k, self.near_v = placed["near_k"], placed["near_v"]
+            # build the pool/near buffers on their KV-head sharding, so each
+            # device allocates only its head slice (a whole pool built on
+            # one device first would have to fit there) and every jitted
+            # step consumes and produces the sharded layout
+            named = to_named(cache_specs(jax.eval_shape(zeros), arch,
+                                         self.mesh), self.mesh)
+            kv = jax.jit(zeros, out_shardings=named)()
+        else:
+            kv = zeros()
+        self.pool_k, self.pool_v = kv["pool_k"], kv["pool_v"]
+        self.near_k, self.near_v = kv["near_k"], kv["near_v"]
         self.tier = tkv.init_tier_state(cfg.n_slots, self.n_pages,
                                         self.pool_pages, cfg.tier.near_pages)
         self.pool = PagePool(self.pool_pages)
@@ -800,6 +809,8 @@ class ServingEngine:
                                                       tokens, meta)
                 self.pool_k = new_cache["pool_k"]
                 self.pool_v = new_cache["pool_v"]
+                self.near_k = new_cache["near_k"]
+                self.near_v = new_cache["near_v"]
                 if self.fused:
                     # the walk's accounting (device) + an independent host
                     # shadow: both must equal the live non-promoted page rows

@@ -62,19 +62,9 @@ def gpipe_apply(block_fn, layer_params, x, mesh: Mesh,
     xspec = P(data_axes if data_axes else None)   # batch over data, repl. over pod
     manual = set(mesh.axis_names)
 
-    def _shard_map(f):
-        # jax >= 0.6 exposes jax.shard_map(axis_names=..., check_vma=...);
-        # on 0.4.x the same fully-manual mode is the experimental API's
-        # default (auto=frozenset()) with check_rep as the toggle.
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(f, mesh=mesh, axis_names=manual,
-                                 in_specs=(pspec, xspec), out_specs=xspec,
-                                 check_vma=False)
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=(pspec, xspec),
-                         out_specs=xspec, check_rep=False)
-
-    @_shard_map
+    @functools.partial(jax.shard_map, mesh=mesh, axis_names=manual,
+                       in_specs=(pspec, xspec), out_specs=xspec,
+                       check_vma=False)
     def run(params_local, x_local):
         stage = jax.lax.axis_index(axis)
         n_ticks = n_microbatches + n_stages - 1

@@ -42,11 +42,11 @@ class TestWalker:
             def body(c, xi):
                 return c + jnp.sin(xi).sum(), None   # sin inside scan
             out, _ = jax.lax.scan(body, 0.0, x)
-            return jax.jit(jnp.cos)(out)             # cos inside pjit
+            return jax.jit(jnp.cos)(out)             # cos inside jit
         walked = walker.collect_eqns(jax.make_jaxpr(f)(jnp.ones((3, 4))))
         prims = {(we.prim, we.path) for we in walked}
         assert ("sin", ("scan",)) in prims
-        assert ("cos", ("pjit",)) in prims
+        assert ("cos", ("jit",)) in prims
 
     def test_intermediate_shapes_spans_depths(self):
         def f(x):
@@ -70,10 +70,10 @@ class TestWalker:
         assert walker.TAINT_RAW not in dots[1].in_taints
 
     def test_taint_flows_through_call_primitives(self):
-        """pjit/scan outputs inherit their sub-jaxpr's taint: a padded /
+        """jit/scan outputs inherit their sub-jaxpr's taint: a padded /
         scanned KV buffer is still raw KV (the suffix-prefill shape)."""
         def f(kv, q):
-            kp = jnp.pad(kv, ((0, 2), (0, 0)))       # pjit-wrapped pad
+            kp = jnp.pad(kv, ((0, 2), (0, 0)))       # jit-wrapped pad
 
             def body(c, row):
                 return c + (q @ row), None           # row is scanned raw KV
@@ -209,15 +209,14 @@ class TestPlantedViolations:
         """A shard_map collective over a 1-device mesh whose axis name is
         NOT declared anywhere — runs on any host, no forced devices."""
         import numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(np.asarray(jax.devices()[:1]), ("rogue",))
 
         def stepish(x):
-            return shard_map(lambda xs: jax.lax.psum(xs, "rogue"),
-                             mesh=mesh, in_specs=(P(),), out_specs=P(),
-                             check_rep=False)(x)
+            return jax.shard_map(lambda xs: jax.lax.psum(xs, "rogue"),
+                                 mesh=mesh, in_specs=(P(),), out_specs=P(),
+                                 check_vma=False)(x)
         return _target(stepish, (jnp.ones((4,)),), check_collectives=True,
                        **kw)
 

@@ -222,8 +222,9 @@ class PagedWorld:
             if can[b]:
                 kv = _kv(int(self.pos[b]), int(new_toks[b]))
                 kn[b, 0], vn[b, 0] = kv[0], kv[1]
-        pos = jnp.asarray(np.where(can, self.pos, 0), jnp.int32)
-        # inactive rows route through page_table -1 -> dropped writes
+        pos = jnp.asarray(self.pos, jnp.int32)
+        # inactive rows route through page_table -1 and full rows
+        # (pos == MAX_LEN) past the capacity -> dropped writes
         cache = tkv.paged_append_token(self.cache, jnp.asarray(kn),
                                        jnp.asarray(vn), pos, self.cfg)
         self.cache = cache

@@ -103,11 +103,10 @@ class TestGradCompression:
         total_c = jnp.zeros((256,))
         total_t = jnp.zeros((256,))
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        @partial(shard_map, mesh=mesh, in_specs=(P(), P()),
-                 out_specs=(P(), P()), check_rep=False)
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
+                 out_specs=(P(), P()), check_vma=False)
         def step(gi, r):
             out, new_r = compression.compress_psum(
                 {"g": gi}, {"g": r}, ("data",))
@@ -127,11 +126,10 @@ class TestGradCompression:
         """The all-reduced payload must be 8-bit (4x compression)."""
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        @partial(shard_map, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                 check_rep=False)
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                 check_vma=False)
         def f(g):
             out, _ = compression.compress_psum(
                 {"g": g}, {"g": jnp.zeros_like(g)}, ("data",))
