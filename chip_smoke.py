@@ -169,6 +169,14 @@ def agreement(a, b) -> float:
         / len(a.outputs)
 
 
+def first_divergence(a, b) -> list:
+    """Per request, the index of the first token that differs between two
+    runs (None where every token agrees)."""
+    return [next((i for i, (x, y) in enumerate(zip(a.outputs[r],
+                                                   b.outputs[r])) if x != y),
+                 None) for r in sorted(a.outputs)]
+
+
 def one_chip(log: CompileLog):
     import jax
     from repro.configs.registry import ARCHS
@@ -204,10 +212,11 @@ def one_chip(log: CompileLog):
                              f"{first}")
     mem = device.memory_stats() or {}
     emit(phase="compare", token_agreement=agreement(dense, fused),
+         first_divergence=first_divergence(dense, fused),
          far_rows_fused=fused.far_rows_touched,
          far_rows_dense=dense.far_rows_touched,
          peak_bytes_in_use=mem.get("peak_bytes_in_use"),
-         **{"programs_total": log.programs, "compile_s_total": log.seconds})
+         programs_total=log.programs, compile_s_total=log.seconds)
 
 
 def four_chips(log: CompileLog):
