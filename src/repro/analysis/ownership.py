@@ -54,9 +54,12 @@ PAIR_RULES = (
 # Per-tick methods, by class: these run on the decode token clock.
 # Boundary methods (_admit/_retire, __init__, shutdown sweeps) are
 # deliberately NOT listed — they run per request, not per token.
+# ``_host`` is the engine's one counted, traced read helper: the per-tick
+# methods pull through it, never directly.
 TICK_METHODS = {
-    "ServingEngine": ("run", "_maintain", "_flush_mapping", "_pin_static",
-                      "_far_rows_shadow", "_account_kv_bytes"),
+    "ServingEngine": ("run", "_serve", "_admit_arrivals", "_decode_tick",
+                      "_maintain", "_flush_mapping", "_pin_static",
+                      "_far_rows_shadow", "_account_kv_bytes", "_host"),
 }
 
 # Host-pull callees flagged inside tick methods.

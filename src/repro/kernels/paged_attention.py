@@ -235,6 +235,7 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
             jax.ShapeDtypeStruct((B, g, Hkv, LANES), jnp.float32),
             jax.ShapeDtypeStruct((B, g, Hkv, LANES), jnp.float32),
         ],
+        name="paged_walk",
         interpret=interpret,
     )(flat(0 if layer is None else layer), flat(walk_pid), flat(walk_live),
       flat(walk_len), flat(near_live), qg, near_k, near_v, pool_k, pool_v)

@@ -95,6 +95,7 @@ def paged_gather(pool: jax.Array, page_ids: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, n_pages * page, D), pool.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=pool_bytes + out_bytes + _VMEM_SLACK),
+        name="paged_gather",
         interpret=interpret,
     )(jnp.asarray(page_ids, jnp.int32).reshape(-1), pool.reshape(P, page, D))
     return out.reshape(B, n_pages * page, Hkv, hd)

@@ -141,6 +141,7 @@ def paged_masses(q: jax.Array, pool_k: jax.Array, score_pid: jax.Array,
                 pltpu.SemaphoreType.DMA(()),
             ]),
         out_shape=jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
+        name="paged_masses",
         interpret=interpret,
     )(flat(score_pid), flat(score_live), flat(score_len), qg, pool_k)
     return out[:, 0]

@@ -77,6 +77,14 @@ reference bit-for-bit with sharing on or off (tests/test_prefix_sharing.py,
 tests/test_serving_engine.py); the paged tiered state drives the byte-cost
 model and, optionally, a pool-native read-path verification probe
 (``verify_tiered_read``).
+
+What the tick loop does on the host is observable from inside: ``run``
+writes constant-named ``serve.*`` spans on the profiler's host plane
+(``_span``; the device planes share its clock), every blocking read of a
+device value goes through ``_host`` (a ``serve.wait.<what>`` span and one
+``ServingReport.host_syncs``), and the report records each decode tick's
+wall times and occupancy, each prefill chunk's rows and each request's
+wall times.
 """
 
 from __future__ import annotations
@@ -104,6 +112,15 @@ from repro.serve.trace import Request
 # separate per-layer arrays — the ownership inversion)
 _TIER_KEYS = ("page_table", "slot_of_page", "page_of_slot", "scores",
               "last_use", "step", "migrations")
+# the leaves ``tkv.paged_release_pages`` edits on the host
+_RELEASE_KEYS = ("scores", "last_use", "slot_of_page", "page_of_slot")
+
+# Spans on the profiler's host plane, on the clock of its device planes.
+# Names are constant strings (trace reductions match them exactly):
+#   serve.run > serve.setup | serve.tick | serve.teardown
+#   serve.tick > serve.admit | serve.prefill | serve.decode | serve.maintain
+#   serve.wait.<what>: one blocking read of a device value (``_host``)
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclass
@@ -294,6 +311,7 @@ class ServingEngine:
            appended) — live KV bytes track demand."""
         cfg = self.cfg
         page = cfg.tier.page
+        self.report.request_wall[req.rid][1] = time.perf_counter()
         prompt = np.asarray(req.prompt, np.int32)
         S = int(prompt.shape[0])
         assert S + req.max_new_tokens <= cfg.max_len, \
@@ -307,8 +325,7 @@ class ServingEngine:
         if self.prefix is not None:
             fresh, evicted = self.prefix.allocate(n_need - m)
             if evicted:
-                self.tier = tkv.paged_release_pages(self.tier, evicted,
-                                                    cfg.tier)
+                self._release(evicted)
                 self._after_mapping_change()   # eviction compacts the near
                                                # mapping: shadow is stale
         else:
@@ -348,7 +365,9 @@ class ServingEngine:
             logits, self.pool_k, self.pool_v = self._prefill(
                 self.params, {"tokens": padded}, self.pool_k, self.pool_v,
                 ids)
-        first = int(jnp.argmax(logits[0, s_len - 1]))
+        first = int(self._host(jnp.argmax(logits[0, s_len - 1]),
+                               "serve.wait.first_token"))
+        self._wall_first(req.rid)
 
         # 4. index the prompt's new full pages for later sharers — they are
         #    already in the pool (prefill wrote them); no re-gather
@@ -436,6 +455,7 @@ class ServingEngine:
                 logits, self.pool_k, self.pool_v = self._prefill_chunk(
                     self.params, {"tokens": padded, "positions": positions},
                     self.pool_k, self.pool_v, prefix_ids, ids, t_pre=c0)
+            self.report.prefill_rows.append((c0, c0 + n))
             job.cursor += n
             chunk_toks += n
             self.report.prefill_tokens += n
@@ -446,7 +466,10 @@ class ServingEngine:
                     self.prefix.insert(job.prompt[:n_full * page],
                                        job.row[:n_full])
             if job.cursor >= job.S:
-                done.append((slot, job, int(jnp.argmax(logits[0, n - 1]))))
+                first = int(self._host(jnp.argmax(logits[0, n - 1]),
+                                       "serve.wait.first_token"))
+                self._wall_first(job.req.rid)
+                done.append((slot, job, first))
                 del self.pending[slot]
         return chunk_toks, done
 
@@ -487,8 +510,7 @@ class ServingEngine:
         pids = [int(p) for p in self.pt_host[slot] if p >= 0]
         freed = self.pool.release(pids)
         if freed:
-            self.tier = tkv.paged_release_pages(self.tier, freed,
-                                                self.cfg.tier)
+            self._release(freed)
         self.pt_host[slot] = -1
         self.tier["page_table"] = self.tier["page_table"].at[slot].set(-1)
         self._after_mapping_change()
@@ -496,6 +518,31 @@ class ServingEngine:
         self.free.sort()
 
     # -- pool-native bookkeeping ---------------------------------------------
+
+    def _host(self, x, span: str) -> np.ndarray:
+        """Every blocking read of a device value on the tick path goes
+        through here: the host waits for ``x`` inside the ``span``
+        (``serve.wait.<what>``), and ``ServingReport.host_syncs`` counts
+        the read."""
+        with _span(span):
+            out = np.asarray(x)
+        self.report.host_syncs += 1
+        return out
+
+    def _wall_first(self, rid: int):
+        """The request's first token is on the host now (it is also its
+        last until decode emits another)."""
+        wall = self.report.request_wall[rid]
+        wall[2] = wall[3] = time.perf_counter()
+
+    def _release(self, pages):
+        """Reset the tier state of pool pages leaving allocation: the host
+        surgery of ``tkv.paged_release_pages`` on read-back copies of the
+        leaves it edits."""
+        host = {k: self._host(self.tier[k], "serve.wait.mapping")
+                for k in _RELEASE_KEYS}
+        self.tier = tkv.paged_release_pages({**self.tier, **host}, pages,
+                                            self.cfg.tier)
 
     def _after_mapping_change(self):
         """Mark the per-layer near buffers / host residency mirror stale
@@ -523,11 +570,11 @@ class ServingEngine:
         else:
             self.near_k, self.near_v = self._sync_near(
                 self.pool_k, self.pool_v, self.tier["page_of_slot"])
-        sop = np.asarray(self.tier["slot_of_page"])
+        sop = self._host(self.tier["slot_of_page"], "serve.wait.mapping")
         self._promoted_host = (self.pt_host >= 0) \
             & (sop[np.maximum(self.pt_host, 0)] >= 0)
-        self._near_used = int(
-            (np.asarray(self.tier["page_of_slot"]) >= 0).sum())
+        self._near_used = int((self._host(self.tier["page_of_slot"],
+                                          "serve.wait.mapping") >= 0).sum())
         self._mapping_dirty = False
 
     def _far_rows_shadow(self) -> int:
@@ -618,8 +665,8 @@ class ServingEngine:
         later migration, no eviction of earlier pins)."""
         cfg = self.cfg
         tier = cfg.tier
-        ros = np.asarray(self.tier["page_of_slot"])
-        sop = np.asarray(self.tier["slot_of_page"])
+        ros = self._host(self.tier["page_of_slot"], "serve.wait.mapping")
+        sop = self._host(self.tier["slot_of_page"], "serve.wait.mapping")
         free_slots = [c for c in range(ros.shape[0]) if ros[c] < 0]
         complete = ((np.arange(self.n_pages)[None, :] + 1) * tier.page
                     <= self.pos[:, None])
@@ -653,14 +700,17 @@ class ServingEngine:
         if tier.policy.upper() == "STATIC":
             need = active & ~self._static_pinned
             if need.any():
-                clock = self._pin_static(np.asarray(masses_dev), need, clock)
+                clock = self._pin_static(
+                    self._host(masses_dev, "serve.wait.masses"), need, clock)
                 self._after_mapping_change()
         else:
-            before = int(self.tier["migrations"])
+            before = int(self._host(self.tier["migrations"],
+                                    "serve.wait.migrations"))
             self.tier = self._plan(self.tier, self.pool_k, self.pool_v,
                                    self.near_k, self.near_v, q0, pos_vec,
                                    idle, masses_dev)
-            moved = int(self.tier["migrations"]) - before
+            moved = int(self._host(self.tier["migrations"],
+                                   "serve.wait.migrations")) - before
             clock = self._bill_migration(clock, moved)
             self.report.migrations += moved
             if moved:     # mapping unchanged when nothing migrated
@@ -671,7 +721,7 @@ class ServingEngine:
             # swaps it in at the next tick boundary — the double buffer
             self._shadow_near = self._sync_near(
                 self.pool_k, self.pool_v, self.tier["page_of_slot"])
-        sop = np.asarray(self.tier["slot_of_page"])
+        sop = self._host(self.tier["slot_of_page"], "serve.wait.mapping")
         promoted = (self.pt_host >= 0) & (sop[np.maximum(self.pt_host, 0)]
                                           >= 0)              # (B, n_pages)
         self._near_tokens = promoted.sum(axis=1) * tier.page
@@ -679,7 +729,7 @@ class ServingEngine:
         # rate, in attention-mass units) — a shared promoted page counts for
         # every referencing slot: one IST, many accesses
         if active.any():
-            masses = np.asarray(masses_dev)
+            masses = self._host(masses_dev, "serve.wait.masses")
             tot = masses[active].sum()
             if tot > 0:
                 self.report.near_hit_mass.append(
@@ -689,8 +739,9 @@ class ServingEngine:
                 got, want = self._probe(self.tier, self.pool_k, self.pool_v,
                                         self.near_k, self.near_v, q0,
                                         pos_vec)
-                err = float(jnp.max(jnp.abs(
-                    (got - want)[jnp.asarray(active)])))
+                err = float(self._host(
+                    jnp.max(jnp.abs((got - want)[jnp.asarray(active)])),
+                    "serve.wait.probe"))
                 self.report.max_read_err = max(self.report.max_read_err, err)
         return clock
 
@@ -698,11 +749,35 @@ class ServingEngine:
 
     def run(self, trace: list[Request], scenario: str = "trace") -> ServingReport:
         """Replay an offline arrival trace to completion."""
+        with _span("serve.run"):
+            with _span("serve.setup"):
+                self._start(len(trace), scenario)
+            t0 = time.perf_counter()
+            clock, steps = self._serve(
+                deque(sorted(trace, key=lambda r: (r.arrival, r.rid))))
+            with _span("serve.teardown"):
+                if self.cfg.overlap_migration:
+                    # the background lane finishes draining after the last
+                    # token
+                    clock = max(clock, self._lane_free)
+                self._assert_zero_orphans()
+                self.report.steps = steps
+                self.report.wall_s = time.perf_counter() - t0
+                self.report.modeled_time = clock
+                self.report.slot_history = dict(self.slot_history)
+                if self.prefix is not None:
+                    self.report.prefix_lookups = self.prefix.stats.lookups
+                    self.report.prefix_hits = self.prefix.stats.hits
+            return self.report
+
+    def _start(self, n_requests: int, scenario: str):
+        """A fresh report, an empty pool and near tier, and the host
+        mirrors of the slot pool."""
         cfg = self.cfg
         arch = self.arch
         self.report = ServingReport(scenario=scenario,
                                     policy=cfg.tier.policy,
-                                    n_requests=len(trace))
+                                    n_requests=n_requests)
         hd = arch.resolved_head_dim
         dtype = jnp.bfloat16
         # THE KV store: per-layer shared page pool + per-layer global near
@@ -758,124 +833,143 @@ class ServingEngine:
             arch.n_layers * cfg.n_slots * cfg.max_len
             * arch.n_kv_heads * hd * jnp.dtype(dtype).itemsize * 2)
 
-        queue = deque(sorted(trace, key=lambda r: (r.arrival, r.rid)))
-        chunked = cfg.prefill_chunk_tokens is not None
+    def _serve(self, queue: deque) -> tuple[float, int]:
+        """The tick loop, until every request has retired.  Returns the
+        modeled clock and the decode steps run."""
+        cfg = self.cfg
         tick, clock, steps = 0, 0.0, 0
-        t0 = time.perf_counter()
         while queue or self.pending \
                 or any(s is not None for s in self.slots):
-            for req in queue:                  # sorted by arrival: stop early
-                if req.arrival > tick:
-                    break
-                if req.rid not in self._visible_clock:
-                    self._visible_clock[req.rid] = clock
-            while queue and queue[0].arrival <= tick and self.free:
-                slot = self.free.pop(0)
-                if chunked:
-                    self._admit_chunked(queue.popleft(), slot)
-                else:
-                    clock = self._admit(queue.popleft(), slot, clock)
-            # a request may want exactly the prefill token (max_new_tokens=1)
-            for b in range(cfg.n_slots):
-                st = self.slots[b]
-                if st is not None and len(st.emitted) >= st.req.max_new_tokens:
-                    self._retire(b)
-            self._account_kv_bytes()
-            active_idx = [b for b, s in enumerate(self.slots) if s is not None]
-            # the chunked prefill lane: at most ``prefill_chunk_tokens`` of
-            # pending prompt work rides this tick, sharing the decode
-            # step's weight stream instead of stalling it
-            chunk_toks, completed = 0, []
-            if self.pending:
-                chunk_toks, completed = self._advance_prefills(
-                    self._prefill_budget(len(active_idx)))
-            if not active_idx and not chunk_toks and not completed:
-                if queue:
-                    tick = max(tick + 1, queue[0].arrival)  # idle fast-forward
-                else:
-                    tick += 1       # unreachable guard: pending implies work
-                continue
+            with _span("serve.tick"):
+                with _span("serve.admit"):
+                    clock = self._admit_arrivals(queue, tick, clock)
+                active_idx = [b for b, s in enumerate(self.slots)
+                              if s is not None]
+                # the chunked prefill lane: at most ``prefill_chunk_tokens``
+                # of pending prompt work rides this tick, sharing the decode
+                # step's weight stream instead of stalling it
+                chunk_toks, completed = 0, []
+                if self.pending:
+                    with _span("serve.prefill"):
+                        chunk_toks, completed = self._advance_prefills(
+                            self._prefill_budget(len(active_idx)))
+                if not active_idx and not chunk_toks and not completed:
+                    if queue:             # idle fast-forward
+                        tick = max(tick + 1, queue[0].arrival)
+                    else:
+                        tick += 1   # unreachable guard: pending implies work
+                    continue
 
-            ran_decode = False
-            if active_idx:
-                self._flush_mapping()
-                pos_dev = jnp.asarray(self.pos, jnp.int32)
-                tokens = {"tokens": jnp.asarray(self.tok[:, None], jnp.int32)}
-                meta = self._meta(self.tier, pos_dev)
-                kv_cache = {"pool_k": self.pool_k, "pool_v": self.pool_v,
-                            "near_k": self.near_k, "near_v": self.near_v,
-                            "pos": pos_dev}
-                logits, new_cache, aux = self._decode(self.params, kv_cache,
-                                                      tokens, meta)
-                self.pool_k = new_cache["pool_k"]
-                self.pool_v = new_cache["pool_v"]
-                self.near_k = new_cache["near_k"]
-                self.near_v = new_cache["near_v"]
-                if self.fused:
-                    # the walk's accounting (device) + an independent host
-                    # shadow: both must equal the live non-promoted page rows
-                    self.report.far_rows_touched += int(meta["walk_live"].sum())
-                    self.report.far_rows_host += self._far_rows_shadow()
+                if active_idx:
+                    with _span("serve.decode"):
+                        clock, q0 = self._decode_tick(active_idx,
+                                                      chunk_toks, clock)
+                    steps += 1
                 else:
-                    # the materializing path gathers the full far view
-                    self.report.far_rows_touched += \
-                        self.n_pages * cfg.tier.page * cfg.n_slots
-                self.report.far_rows_dense += \
-                    self.n_pages * cfg.tier.page * cfg.n_slots
-                toks = np.asarray(jnp.argmax(logits, axis=-1))[:, 0]
+                    # prefill-only tick: the chunks stream the weights alone
+                    clock += cfg.cost.prefill_cost(chunk_toks)
+                for slot, job, first in completed:
+                    self._complete_prefill(slot, job, first, clock)
+                if active_idx and steps % cfg.tier.interval == 0:
+                    # cost-aware deferral gate (the WMC queue-idle gate
+                    # generalized to all four policies): while the run queue
+                    # is hot — arrivals waiting or prompts still chunking —
+                    # keep migration bandwidth off the critical path,
+                    # bounded by ``defer_limit`` so sustained load still
+                    # gets maintenance
+                    hot = bool(self.pending) \
+                        or bool(queue and queue[0].arrival <= tick)
+                    if hot and self._defer_count < cfg.defer_limit:
+                        self._defer_count += 1
+                        self.report.migration_deferrals += 1
+                    else:
+                        self._defer_count = 0
+                        with _span("serve.maintain"):
+                            clock = self._maintain(q0, clock, not hot)
+                tick += 1
+        return clock, steps
 
-                live = self.pos[active_idx] + 1
-                # one fused iteration: decode KV sweep + piggybacked chunk
-                # tokens share the tick's weight stream
-                clock += cfg.cost.decode_step_cost(
-                    self._near_tokens[active_idx], live,
-                    kv_shards=self.kv_shards) \
-                    + cfg.cost.chunk_prefill_cost(chunk_toks)
-                steps += 1
-                ran_decode = True
-                for b in active_idx:
-                    st = self.slots[b]
-                    st.emitted.append(int(toks[b]))
-                    self.report.token_latencies.append(clock - st.last_emit)
-                    st.last_emit = clock
-                    self.report.tokens += 1
-                    self.pos[b] += 1
-                    self.tok[b] = int(toks[b])
-                    if len(st.emitted) >= st.req.max_new_tokens:
-                        self._retire(b)
+    def _admit_arrivals(self, queue: deque, tick: int, clock: float) -> float:
+        """Make this tick's arrivals visible, admit waiting requests into
+        free slots, retire requests that want only the prefill token, and
+        account the live KV bytes."""
+        now = time.perf_counter()
+        for req in queue:                  # sorted by arrival: stop early
+            if req.arrival > tick:
+                break
+            if req.rid not in self._visible_clock:
+                self._visible_clock[req.rid] = clock
+                self.report.request_wall[req.rid] = [now] + [np.nan] * 3
+        while queue and queue[0].arrival <= tick and self.free:
+            slot = self.free.pop(0)
+            if self.cfg.prefill_chunk_tokens is not None:
+                self._admit_chunked(queue.popleft(), slot)
             else:
-                # prefill-only tick: the chunks stream the weights alone
-                clock += cfg.cost.prefill_cost(chunk_toks)
-            for slot, job, first in completed:
-                self._complete_prefill(slot, job, first, clock)
-            if ran_decode and steps % cfg.tier.interval == 0:
-                # cost-aware deferral gate (the WMC queue-idle gate
-                # generalized to all four policies): while the run queue is
-                # hot — arrivals waiting or prompts still chunking — keep
-                # migration bandwidth off the critical path, bounded by
-                # ``defer_limit`` so sustained load still gets maintenance
-                hot = bool(self.pending) \
-                    or bool(queue and queue[0].arrival <= tick)
-                if hot and self._defer_count < cfg.defer_limit:
-                    self._defer_count += 1
-                    self.report.migration_deferrals += 1
-                else:
-                    self._defer_count = 0
-                    clock = self._maintain(aux["q0"], clock, not hot)
-            tick += 1
+                clock = self._admit(queue.popleft(), slot, clock)
+        # a request may want exactly the prefill token (max_new_tokens=1)
+        for b in range(self.cfg.n_slots):
+            st = self.slots[b]
+            if st is not None and len(st.emitted) >= st.req.max_new_tokens:
+                self._retire(b)
+        self._account_kv_bytes()
+        return clock
 
-        if cfg.overlap_migration:
-            # the background lane finishes draining after the last token
-            clock = max(clock, self._lane_free)
-        self._assert_zero_orphans()
-        self.report.steps = steps
-        self.report.wall_s = time.perf_counter() - t0
-        self.report.modeled_time = clock
-        self.report.slot_history = dict(self.slot_history)
-        if self.prefix is not None:
-            self.report.prefix_lookups = self.prefix.stats.lookups
-            self.report.prefix_hits = self.prefix.stats.hits
-        return self.report
+    def _decode_tick(self, active_idx: list, chunk_toks: int,
+                     clock: float) -> tuple[float, jax.Array]:
+        """One batched decode step over the active slots: emit a token for
+        each, retire the finished.  Returns the clock and the step's layer-0
+        queries (what ``_maintain`` scores with)."""
+        cfg = self.cfg
+        self._flush_mapping()
+        pos_dev = jnp.asarray(self.pos, jnp.int32)
+        tokens = {"tokens": jnp.asarray(self.tok[:, None], jnp.int32)}
+        meta = self._meta(self.tier, pos_dev)
+        kv_cache = {"pool_k": self.pool_k, "pool_v": self.pool_v,
+                    "near_k": self.near_k, "near_v": self.near_v,
+                    "pos": pos_dev}
+        live = self.pos[active_idx] + 1
+        t_dispatch = time.perf_counter()
+        logits, new_cache, aux = self._decode(self.params, kv_cache, tokens,
+                                              meta)
+        self.pool_k = new_cache["pool_k"]
+        self.pool_v = new_cache["pool_v"]
+        self.near_k = new_cache["near_k"]
+        self.near_v = new_cache["near_v"]
+        if self.fused:
+            # the walk's accounting (device) + an independent host shadow:
+            # both must equal the live non-promoted page rows
+            self.report.far_rows_touched += int(self._host(
+                meta["walk_live"].sum(), "serve.wait.walk_rows"))
+            self.report.far_rows_host += self._far_rows_shadow()
+        else:
+            # the materializing path gathers the full far view
+            self.report.far_rows_touched += \
+                self.n_pages * cfg.tier.page * cfg.n_slots
+        self.report.far_rows_dense += \
+            self.n_pages * cfg.tier.page * cfg.n_slots
+        toks = self._host(jnp.argmax(logits, axis=-1),
+                          "serve.wait.tokens")[:, 0]
+        t_tokens = time.perf_counter()
+        self.report.decode_ticks.append(
+            (t_dispatch, t_tokens, len(active_idx), int(live.sum())))
+
+        # one fused iteration: decode KV sweep + piggybacked chunk tokens
+        # share the tick's weight stream
+        clock += cfg.cost.decode_step_cost(
+            self._near_tokens[active_idx], live, kv_shards=self.kv_shards) \
+            + cfg.cost.chunk_prefill_cost(chunk_toks)
+        for b in active_idx:
+            st = self.slots[b]
+            st.emitted.append(int(toks[b]))
+            self.report.token_latencies.append(clock - st.last_emit)
+            st.last_emit = clock
+            self.report.request_wall[st.req.rid][3] = t_tokens
+            self.report.tokens += 1
+            self.pos[b] += 1
+            self.tok[b] = int(toks[b])
+            if len(st.emitted) >= st.req.max_new_tokens:
+                self._retire(b)
+        return clock, aux["q0"]
 
 
 class DataParallelEngine:

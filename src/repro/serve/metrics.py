@@ -127,6 +127,21 @@ class ServingReport:
                                      # cost-aware deferral gate
     migration_stall: float = 0.0     # modeled time the background
                                      # migration lane was saturated
+    # what the tick loop did on the host, on the wall clock
+    # (``time.perf_counter`` seconds); recorded where the work happens,
+    # with no device read of their own
+    host_syncs: int = 0              # blocking reads of device values
+                                     # (``ServingEngine._host``)
+    decode_ticks: list = field(default_factory=list)
+                                     # per decode step: (t_dispatch,
+                                     # t_tokens, active slots, live rows =
+                                     # sum of pos + 1 over them)
+    prefill_rows: list = field(default_factory=list)
+                                     # per prefill chunk: (first row,
+                                     # end row) of the prompt it wrote
+    request_wall: dict = field(default_factory=dict)
+                                     # rid -> [visible, admitted, first
+                                     # token, last token] (nan: not yet)
 
     @property
     def tokens_per_s_wall(self) -> float:
@@ -219,7 +234,7 @@ def merge_lane_reports(lanes: list) -> "ServingReport":
               "prefix_hits", "far_rows_touched", "far_rows_host",
               "far_rows_dense", "kv_bytes_live", "kv_bytes_near",
               "kv_bytes_cached", "kv_bytes_dense_equiv", "prefill_chunks",
-              "migration_deferrals"):
+              "migration_deferrals", "host_syncs"):
         setattr(merged, f, sum(getattr(r, f) for r in lanes))
     merged.wall_s = max(r.wall_s for r in lanes)
     merged.modeled_time = max(r.modeled_time for r in lanes)
@@ -229,6 +244,9 @@ def merge_lane_reports(lanes: list) -> "ServingReport":
         merged.token_latencies.extend(r.token_latencies)
         merged.ttfts.extend(r.ttfts)
         merged.near_hit_mass.extend(r.near_hit_mass)
+        merged.decode_ticks.extend(r.decode_ticks)
+        merged.prefill_rows.extend(r.prefill_rows)
+        merged.request_wall.update(r.request_wall)
         merged.outputs.update(r.outputs)
     for i, r in enumerate(lanes):
         for slot, rids in r.slot_history.items():

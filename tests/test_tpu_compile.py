@@ -121,6 +121,8 @@ def test_paged_masses_compiles(shape):
     compiled = _compile(paged_masses, shape((B, H, HD), jnp.float32),
                         shape((512, PAGE, HKV, HD)), *_walk_args(shape))
     assert compiled.memory_analysis() is not None
+    assert re.search(r"%paged_masses[.\d]* = .*custom_call_target="
+                     r'"tpu_custom_call"', compiled.as_text())
 
 
 def test_paged_gather_compiles_small_pool(shape):
@@ -129,6 +131,8 @@ def test_paged_gather_compiles_small_pool(shape):
     compiled = _compile(paged_gather, shape((64, PAGE, HKV, HD)),
                         shape((B, 32), jnp.int32))
     assert compiled.memory_analysis() is not None
+    assert re.search(r"%paged_gather[.\d]* = .*custom_call_target="
+                     r'"tpu_custom_call"', compiled.as_text())
 
 
 def _decode_step_args(shape, cfg, pool_sharding=None, near_sharding=None,
@@ -177,7 +181,11 @@ def test_full_width_decode_step_fits_one_chip(shape, tpu_backend, fused):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, total
-    assert ("tpu_custom_call" in compiled.as_text()) == fused
+    hlo = compiled.as_text()
+    assert ("tpu_custom_call" in hlo) == fused
+    # the walk keeps its name down to the compiled op, so a trace names it
+    assert bool(re.search(r"%paged_walk[.\d]* = .*custom_call_target="
+                          r'"tpu_custom_call"', hlo)) == fused
 
 
 def test_head_sharded_decode_step_compiles_on_four_chips(topo, shape,
