@@ -13,10 +13,17 @@ gather path itself:
        changes, so it is fetched once per call, not once per slot) under
        per-(slot, near-slot) live counts — the global near tier serves
        every tenant of a promoted page with its own position mask;
-    2. walks the slot's compacted page-table WALK LIST with a `fori_loop`,
-       issuing ONE async pool->VMEM copy of the whole `(page, Hkv, hd)`
-       page (contiguous in HBM) per *mapped, non-promoted, live* page and
-       online-softmaxing it under its partial-last-page live count.
+    2. walks the slot's compacted page-table WALK LIST in blocks of
+       `kb = 128 // page` pages (8 at the serving page of 16) through two
+       VMEM block buffers: one async pool->VMEM copy of the whole
+       `(page, Hkv, hd)` page (contiguous in HBM) per *mapped,
+       non-promoted, live* page, block i+1's copies started before block
+       i is computed (block 0's fly during the near pass), and one
+       online-softmax update — one running-max rescale — per block, each
+       row masked by its page's partial-last-page live count.  Entries
+       past `walk_len` are never copied; their rows in the buffer are
+       stale or uninitialised, so they are masked out of the scores and
+       zeroed in V.
 
   Far bytes touched per step per slot == sum of live, non-promoted page
   rows — never `n_pages * page` (asserted end-to-end by the serving
@@ -38,6 +45,7 @@ partial results exactly.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +56,7 @@ from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 LANES = 128                     # m/l leave the kernel lane-replicated
+BLOCK_ROWS = 128                # far rows per block buffer (kb = 128 // page)
 
 
 def paged_attention_stats(q: jax.Array, pool_k: jax.Array,
@@ -110,7 +119,7 @@ def _paged_attention_kernel(layer_ref, walk_pid_ref, walk_live_ref,
                             walk_len_ref, near_live_ref, q_ref, nk_ref,
                             nv_ref, pool_k_ref, pool_v_ref, o_ref, m_ref,
                             l_ref, kbuf, vbuf, sem, *, page: int,
-                            n_near: int, W: int, scale: float,
+                            n_near: int, W: int, kb: int, scale: float,
                             stacked: bool):
     b = pl.program_id(0)
     if stacked:                         # (L, P, page, Hkv, hd) pools
@@ -120,49 +129,92 @@ def _paged_attention_kernel(layer_ref, walk_pid_ref, walk_live_ref,
     qs = [q_ref[0, j].astype(jnp.float32) * scale for j in range(g)]
     row = jax.lax.broadcasted_iota(jnp.int32, (page, Hkv, 1), 0)
 
-    def update(carry, kp, vp, live):
-        """One page's online-softmax update for every (group, head);
-        rows >= live are dead.  kp/vp: (page, Hkv, hd) f32."""
-        alive = row < live
+    def update(carry, load, lives):
+        """One online-softmax update over ``len(lives)`` pages for every
+        (group, head): one running-max rescale per block, not per page.
+        ``load(p)`` gives page p's (page, Hkv, hd) K and V rows; rows at or
+        past ``lives[p]`` are dead and may hold stale or uninitialised
+        bytes, so they are kept out of the scores AND zeroed in V
+        (0 * NaN is NaN)."""
+        alive = [row < live for live in lives]
+        kvs = [load(p) for p in range(len(lives))]
+        ks = [k.astype(jnp.float32) for k, _ in kvs]
+        vs = [jnp.where(a, v.astype(jnp.float32), 0.0)
+              for a, (_, v) in zip(alive, kvs)]
         new = []
         for j in range(g):
             acc, m, l = carry[j]
-            s = jnp.sum(kp * qs[j][None], axis=-1, keepdims=True)
-            s = jnp.where(alive, s, NEG_INF)                 # (page, Hkv, 1)
-            m_new = jnp.maximum(m, jnp.max(s, axis=0))       # (Hkv, 1)
+            s = [jnp.where(a, jnp.sum(k * qs[j][None], axis=-1,
+                                      keepdims=True), NEG_INF)
+                 for a, k in zip(alive, ks)]            # (page, Hkv, 1) each
+            m_new = m
+            for sp in s:
+                m_new = jnp.maximum(m_new, jnp.max(sp, axis=0))  # (Hkv, 1)
             alpha = jnp.exp(m - m_new)
-            p = jnp.where(alive, jnp.exp(s - m_new[None]), 0.0)
-            new.append((acc * alpha + jnp.sum(p * vp, axis=0),
-                        m_new, l * alpha + jnp.sum(p, axis=0)))
+            acc, l = acc * alpha, l * alpha
+            for a, sp, v in zip(alive, s, vs):
+                p = jnp.where(a, jnp.exp(sp - m_new[None]), 0.0)
+                acc = acc + jnp.sum(p * v, axis=0)
+                l = l + jnp.sum(p, axis=0)
+            new.append((acc, m_new, l))
         return tuple(new)
 
     carry = tuple((jnp.zeros((Hkv, hd), jnp.float32),
                    jnp.full((Hkv, 1), NEG_INF, jnp.float32),
                    jnp.zeros((Hkv, 1), jnp.float32)) for _ in range(g))
 
-    # -- near pass: C resident pages, dense in VMEM ---------------------------
-    def near_body(c, carry):
-        rows = pl.ds(c * page, page)
-        return update(carry, nk_ref[rows].astype(jnp.float32),
-                      nv_ref[rows].astype(jnp.float32),
-                      near_live_ref[b * n_near + c])
+    # -- far copies: the slot's live, non-promoted pages, kb pages a block
+    #    in two block buffers; block 0 flies while the near pass computes --
+    n = walk_len_ref[b]
 
-    carry = jax.lax.fori_loop(0, n_near, near_body, carry)
+    def copies(blk, slot, p):
+        pid = walk_pid_ref[b * W + blk * kb + p]
+        rows = pl.ds(p * page, page)
+        return (pltpu.make_async_copy(pool_k_ref.at[pid],
+                                      kbuf.at[slot, rows], sem.at[0, slot, p]),
+                pltpu.make_async_copy(pool_v_ref.at[pid],
+                                      vbuf.at[slot, rows], sem.at[1, slot, p]))
 
-    # -- far pass: walk the slot's live, non-promoted pages -------------------
-    def far_body(i, carry):
-        pid = walk_pid_ref[b * W + i]
-        cp_k = pltpu.make_async_copy(pool_k_ref.at[pid], kbuf, sem.at[0])
-        cp_v = pltpu.make_async_copy(pool_v_ref.at[pid], vbuf, sem.at[1])
-        cp_k.start()
-        cp_v.start()
-        cp_k.wait()
-        cp_v.wait()
-        return update(carry, kbuf[...].astype(jnp.float32),
-                      vbuf[...].astype(jnp.float32),
-                      walk_live_ref[b * W + i])
+    def each_live_page(blk, slot, op):
+        """Run ``op`` on both copies of every page of block ``blk`` that is
+        on the walk; pages past ``walk_len`` are never copied."""
+        for p in range(kb):
+            @pl.when(blk * kb + p < n)
+            def _(p=p):
+                for cp in copies(blk, slot, p):
+                    op(cp)
 
-    carry = jax.lax.fori_loop(0, walk_len_ref[b], far_body, carry)
+    each_live_page(0, 0, lambda cp: cp.start())
+
+    # -- near pass: C resident VMEM pages, in blocks of kn pages --------------
+    kn = math.gcd(n_near, kb)           # divides C: no block runs past it
+
+    def near_body(i, carry):
+        def load(p):
+            rows = pl.ds((i * kn + p) * page, page)
+            return nk_ref[rows], nv_ref[rows]
+        return update(carry, load, [near_live_ref[b * n_near + i * kn + p]
+                                    for p in range(kn)])
+
+    carry = jax.lax.fori_loop(0, n_near // kn, near_body, carry)
+
+    # -- far pass: block i+1's copies fly while block i computes -------------
+    def far_block(i, carry):
+        slot = i % 2
+        each_live_page(i + 1, 1 - slot, lambda cp: cp.start())
+        each_live_page(i, slot, lambda cp: cp.wait())
+        lives = []
+        for p in range(kb):
+            e = i * kb + p                  # past W only when past n
+            lives.append(jnp.where(
+                e < n, walk_live_ref[b * W + jnp.minimum(e, W - 1)], 0))
+
+        def load(p):
+            rows = pl.ds(p * page, page)
+            return kbuf[slot, rows], vbuf[slot, rows]
+        return update(carry, load, lives)
+
+    carry = jax.lax.fori_loop(0, pl.cdiv(n, kb), far_block, carry)
 
     for j in range(g):
         acc, m, l = carry[j]
@@ -203,8 +255,9 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     # (B, g, Hkv, hd): each GQA group is one (Hkv, hd) panel
     qg = q.reshape(B, Hkv, g, hd).transpose(0, 2, 1, 3)
 
+    kb = max(1, min(W, BLOCK_ROWS // page))      # far pages per block
     kernel = functools.partial(_paged_attention_kernel, page=page,
-                               n_near=n_near, W=W, scale=hd ** -0.5,
+                               n_near=n_near, W=W, kb=kb, scale=hd ** -0.5,
                                stacked=stacked)
     per_slot = lambda b, *_: (b, 0, 0, 0)
     resident = lambda b, *_: (0, 0, 0)
@@ -226,9 +279,9 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                 pl.BlockSpec((1, g, Hkv, LANES), per_slot),
             ],
             scratch_shapes=[
-                pltpu.VMEM((page, Hkv, hd), pool_k.dtype),
-                pltpu.VMEM((page, Hkv, hd), pool_v.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, kb * page, Hkv, hd), pool_k.dtype),
+                pltpu.VMEM((2, kb * page, Hkv, hd), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2, kb)),   # (K|V, buffer, page)
             ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, g, Hkv, hd), jnp.float32),

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 try:                                   # optional fast path: real hypothesis
     from hypothesis import given, settings
@@ -131,12 +132,14 @@ class TestNearDecodeAttention:
                                    rtol=1e-5, atol=1e-5)
 
 
-def _walk_meta(key, B, P, page, n_pages, C):
+def _walk_meta(key, B, P, page, n_pages, C, lens=None):
     """Random but well-formed walk/near metadata for the fused kernel:
     distinct pool ids per slot, live counts in [1, page] (partial last
-    pages included), near live counts in [0, page] (0 = non-tenant)."""
+    pages included), near live counts in [0, page] (0 = non-tenant).
+    ``lens``: the slots' walk lengths (random in [0, n_pages] if None)."""
     ks = jax.random.split(key, 4)
-    walk_len = jax.random.randint(ks[0], (B,), 0, n_pages + 1)
+    walk_len = (jax.random.randint(ks[0], (B,), 0, n_pages + 1)
+                if lens is None else jnp.asarray(lens))
     pid = jnp.stack([jax.random.permutation(k, P)[:n_pages]
                      for k in jax.random.split(ks[1], B)]).astype(jnp.int32)
     walk_live = jax.random.randint(ks[2], (B, n_pages), 1, page + 1)
@@ -148,13 +151,25 @@ class TestPagedAttention:
     """Fused page-table-walking decode kernel vs its jnp oracle (ISSUE 4)."""
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-    @pytest.mark.parametrize("B,H,Hkv,hd,page,n_pages,C,P", [
-        (2, 4, 2, 64, 16, 4, 2, 12),     # GQA
-        (1, 8, 8, 32, 8, 6, 3, 10),      # MHA, more pages than near slots
-        (3, 6, 2, 128, 32, 2, 4, 8),     # wide head, near > far
-        (2, 2, 1, 16, 8, 5, 1, 16),      # MQA, tiny
+    @pytest.mark.parametrize("B,H,Hkv,hd,page,n_pages,C,P,lens", [
+        pytest.param(2, 4, 2, 64, 16, 4, 2, 12, None,       # GQA
+                     id="2-4-2-64-16-4-2-12"),
+        pytest.param(1, 8, 8, 32, 8, 6, 3, 10, None,        # MHA, W > C
+                     id="1-8-8-32-8-6-3-10"),
+        pytest.param(3, 6, 2, 128, 32, 2, 4, 8, None,       # near > far
+                     id="3-6-2-128-32-2-4-8"),
+        pytest.param(2, 2, 1, 16, 8, 5, 1, 16, None,        # MQA, tiny
+                     id="2-2-1-16-8-5-1-16"),
+        # walks across the block edges: kb = 128 // page far pages a
+        # block, W = n_pages not a multiple of kb; lens 0, 1, kb - 1,
+        # kb, kb + 1, 2 kb + 3
+        pytest.param(6, 4, 2, 64, 32, 13, 2, 80, (0, 1, 3, 4, 5, 11),
+                     id="block-edges-kb4"),
+        pytest.param(6, 32, 4, 128, 16, 21, 3, 128, (0, 1, 7, 8, 9, 19),
+                     id="block-edges-kb8-yi-gqa"),          # Hkv 4, g 8
     ])
-    def test_against_ref(self, dtype, B, H, Hkv, hd, page, n_pages, C, P):
+    def test_against_ref(self, dtype, B, H, Hkv, hd, page, n_pages, C, P,
+                         lens):
         ks = jax.random.split(jax.random.key(11), 6)
         q = _rand(ks[0], (B, H, hd), dtype)
         pool_k = _rand(ks[1], (P, page, Hkv, hd), dtype)
@@ -162,7 +177,7 @@ class TestPagedAttention:
         near_k = _rand(ks[3], (C * page, Hkv, hd), dtype)
         near_v = _rand(ks[4], (C * page, Hkv, hd), dtype)
         pid, walk_live, walk_len, near_live = _walk_meta(
-            ks[5], B, P, page, n_pages, C)
+            ks[5], B, P, page, n_pages, C, lens)
         got = paged_attention(q, pool_k, pool_v, near_k, near_v, pid,
                               walk_live, walk_len, near_live, interpret=True)
         want = ref.paged_attention_ref(q, pool_k, pool_v, near_k, near_v,
@@ -194,6 +209,65 @@ class TestPagedAttention:
         assert np.all(np.asarray(out) == 0.0)
         merged = ref.merge_attention_stats([(out, m, l)])
         assert np.isfinite(np.asarray(merged)).all()
+
+    @pytest.mark.parametrize("H,Hkv,hd", [(16, 8, 128), (32, 4, 128)],
+                             ids=["qwen-gqa", "yi-gqa"])
+    def test_poisoned_pool_stays_finite(self, H, Hkv, hd):
+        """Every byte the walk must not read is NaN: the pool pages on no
+        slot's walk (padding entries past ``walk_len`` point at them), the
+        dead rows past each walked page's live count, the near rows dead
+        for every slot, and (TPU interpret mode) all uninitialised VMEM,
+        so the block buffers' stale rows are NaN too.  A copy of the wrong
+        page, or a dead row reaching the scores or the p·v sum, turns the
+        output NaN; the double-buffered copies must not race the reads."""
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+        B, page, C, W, P = 6, 16, 3, 21, 160
+        lens = np.array([0, 1, 7, 8, 9, 19], np.int32)     # kb = 8
+        ks = jax.random.split(jax.random.key(13), 6)
+        q = _rand(ks[0], (B, H, hd), jnp.float32)
+        pool_k = np.array(_rand(ks[1], (P, page, Hkv, hd), jnp.float32))
+        pool_v = np.array(_rand(ks[2], (P, page, Hkv, hd), jnp.float32))
+        near_k = np.array(_rand(ks[3], (C * page, Hkv, hd), jnp.float32))
+        near_v = np.array(_rand(ks[4], (C * page, Hkv, hd), jnp.float32))
+        rng = np.random.default_rng(13)
+        own = rng.permutation(P)              # disjoint pages per slot
+        pid = np.zeros((B, W), np.int32)
+        walk_live = np.zeros((B, W), np.int32)
+        walked = own[:lens.sum()]
+        pid[:] = own[-1]                      # padding -> a poisoned page
+        for b, (lo, n) in enumerate(zip(np.cumsum(lens) - lens, lens)):
+            pid[b, :n] = own[lo:lo + n]
+            walk_live[b, :n] = rng.integers(1, page + 1, n)
+        near_live = rng.integers(0, page + 1, (B, C)).astype(np.int32)
+        args = (pid, walk_live, lens, near_live)
+        want = ref.paged_attention_ref(q, pool_k, pool_v, near_k, near_v,
+                                       *args)
+
+        bad = np.ones(P, bool)
+        bad[walked] = False
+        for arr in (pool_k, pool_v):
+            arr[bad] = np.nan
+            for b in range(B):
+                for i in range(lens[b]):
+                    arr[pid[b, i], walk_live[b, i]:] = np.nan
+        for arr in (near_k, near_v):
+            for c in range(C):
+                arr[c * page + near_live[:, c].max():(c + 1) * page] = \
+                    np.nan
+        got = paged_attention(
+            q, pool_k, pool_v, near_k, near_v, *args,
+            interpret=pltpu.InterpretParams(uninitialized_memory="nan",
+                                            detect_races=True))
+        assert not interpret_pallas_call.races.races_found
+        for a in got:
+            assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want[1]),
+                                   rtol=2e-5, atol=2e-5)
+        g = np.asarray(got[0]) / np.maximum(np.asarray(got[2])[..., None],
+                                            1e-30)
+        w = np.asarray(want[0]) / np.maximum(np.asarray(want[2])[..., None],
+                                             1e-30)
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5)
 
     @given(seed=st.integers(0, 99))
     @settings(max_examples=10, deadline=None)

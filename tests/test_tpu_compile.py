@@ -98,16 +98,22 @@ def _walk_args(shape, W=N_PAGES):
     return (shape((B, W), i32), shape((B, W), i32), shape((B,), i32))
 
 
-@pytest.mark.parametrize("stacked", [False, True],
-                         ids=["one-layer-pool", "stacked-pools"])
-def test_paged_attention_compiles(shape, stacked):
+@pytest.mark.parametrize("stacked,heads,kv_heads", [
+    pytest.param(False, H, HKV, id="one-layer-pool"),
+    pytest.param(True, H, HKV, id="stacked-pools"),
+    pytest.param(True, 32, 4, id="yi-9b-stacked-pools"),     # GQA group 8
+])
+def test_paged_attention_compiles(shape, stacked, heads, kv_heads):
     """The fused walk, on one layer's pool and on the decode step's
-    stacked (L, P, page, Hkv, hd) pools read at a traced layer."""
+    stacked (L, P, page, Hkv, hd) pools read at a traced layer, at the
+    deployment's W = 256 pages a slot: the two block buffers and their
+    DMA semaphores pass the chip's compiler at Qwen3-1.7B's and Yi-9B's
+    widths."""
     lead = (2,) if stacked else ()
-    pool = shape(lead + (512, PAGE, HKV, HD))
-    near = shape((NEAR * PAGE, HKV, HD))
-    args = (shape((B, H, HD)), pool, pool, near, near, *_walk_args(shape),
-            shape((B, NEAR), jnp.int32))
+    pool = shape(lead + (512, PAGE, kv_heads, HD))
+    near = shape((NEAR * PAGE, kv_heads, HD))
+    args = (shape((B, heads, HD)), pool, pool, near, near,
+            *_walk_args(shape), shape((B, NEAR), jnp.int32))
     if stacked:
         fn = lambda *a: paged_attention(*a[:-1], layer=a[-1])
         args += (shape((), jnp.int32),)
