@@ -5,11 +5,12 @@
 
 For each seed, in one process and on one engine: the seed's weights and
 requests, one whole replay of the cell's trace through the timed path, the
-engine freed, then the sampled requests scored as ``run.py`` scores them:
-the widest gap of a served token below the float32 reference's best
-(the program's reading), the widest gap of the token that the float8
-control puts first (the control's reading), and the same for the
-reference with only its K/V rows in float8.  One JSON line per seed, each
+engine freed, then the sampled requests scored as ``run.py`` scores them,
+through the configuration's family (``families/``): the widest gap of a
+served token below the float32 reference's best (the program's reading),
+and for each of the family's controls (dense: ``fp8``, every matmul input
+and K/V row in float8, and ``fp8_kv``, the K/V rows alone) the widest gap
+of the token that the control puts first.  One JSON line per seed, each
 reading beside the cell's limit, then a summary line.  The limit lies
 between the largest program reading and the smallest control reading
 (``limits/<cell>.json``).
@@ -35,25 +36,25 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     run.setup_jax()
     import correctness
+    import families
     import hooks
-    import model
-    import reference
     import workload as traffic
 
     bench = run.benchmark()
     cell = run.cell_of(bench, args.workload)
     run.chips(cell["chips"])
-    cfg = model.load_config(cell["config"])
+    cfg = families.load_config(cell["config"])
+    fam = families.of(cfg)
     mix = traffic.load_mix(cell["traffic"])
-    dm = model.dims(cfg)
+    dm = fam.dims(cfg)
     limit = correctness.limits(args.workload)["served_logit_gap"]
     eng = probe = None
     lines = []
     for seed in [int(s) for s in args.seeds.split(",")]:
         t = time.perf_counter()
-        params = hooks.program_params(seed, dm)
+        params = fam.program_params(seed, dm)
         if eng is None:
-            eng = hooks.ServingEngine(params, hooks.arch_config(cfg, dm),
+            eng = hooks.ServingEngine(params, fam.arch_config(cfg, dm),
                                       hooks.serving_config(cfg["engine"]))
             probe = hooks.EngineProbe(eng)
         else:
@@ -65,23 +66,22 @@ def main(argv=None) -> int:
         del params
         gc.collect()
         picks = correctness.sample(reqs, seed)
-        got = correctness.served_gap(seed, dm, reqs, rep.outputs, picks,
-                                     reference.CONTROLS)
-        line = {"seed": seed, "program_gap": got["gap"],
-                "control_gap": got["controls"]["fp8"],
-                "kv_fp8_gap": got["controls"]["fp8_kv"], "limit": limit,
-                "program_within": got["gap"] <= limit,
-                "control_within": got["controls"]["fp8"] <= limit,
-                "kv_fp8_within": got["controls"]["fp8_kv"] <= limit,
-                "tokens": got["tokens"], "failed": failed,
-                "seconds": time.perf_counter() - t}
+        got = correctness.served_gap(fam, seed, dm, reqs, rep.outputs, picks,
+                                     fam.CONTROLS)
+        line = {"seed": seed, "program_gap": got["gap"], "limit": limit,
+                "program_within": got["gap"] <= limit}
+        for c, g in got["controls"].items():
+            line[f"{c}_gap"] = g
+            line[f"{c}_within"] = g <= limit
+        line.update(tokens=got["tokens"], failed=failed,
+                    seconds=time.perf_counter() - t)
         lines.append(line)
         print(json.dumps(line), flush=True)
     print(json.dumps({
         "workload": args.workload, "seeds": len(lines),
         "program_gap_max": max(x["program_gap"] for x in lines),
-        "control_gap_min": min(x["control_gap"] for x in lines),
-        "kv_fp8_gap_min": min(x["kv_fp8_gap"] for x in lines),
+        **{f"{c}_gap_min": min(x[f"{c}_gap"] for x in lines)
+           for c in fam.CONTROLS},
         "limit": limit}),
         flush=True)
     return 0

@@ -2,10 +2,11 @@
 
 Once the window has closed and the engine's buffers are freed, a sample of
 the requests that the window's last replay finished, drawn from the seed
-and always holding the longest one, is scored by the float32 reference:
-the prompt and the served tokens go through ``reference.logits_at``, and
-at each served position the gap by which the served token's logit lies
-below the reference's best is read.  The widest gap over the sample is
+and always holding the longest one, is scored by the float32 reference of
+the configuration's family (``families/<family>_reference.py``): the
+prompt and the served tokens go through it, and at each served position
+the gap by which the served token's logit lies below the reference's best
+is read.  The widest gap over the sample is
 compared with the cell's limit (``limits/<cell>.json``), and every request
 of the window has to have got all of its tokens.
 
@@ -21,9 +22,6 @@ import json
 from pathlib import Path
 
 import numpy as np
-
-import model
-import reference
 
 LIMITS_DIR = Path(__file__).resolve().parent / "limits"
 SAMPLE = 6                   # requests scored, the longest among them
@@ -53,20 +51,20 @@ def failed_requests(reqs: list[dict], outputs: dict, vocab: int) -> int:
     return bad
 
 
-def served_gap(seed: int, dm: model.Dims, reqs: list[dict], outputs: dict,
+def served_gap(fam, seed: int, dm, reqs: list[dict], outputs: dict,
                picks: list[int], controls=()) -> dict:
     """Widest gap over the sampled requests and the tokens scored, and for
-    each lower precision in ``controls`` (``reference.CONTROLS``) that
+    each lower precision in ``controls`` (the family's ``CONTROLS``) that
     pass's widest gap on the same sequences.  A request without all its
     tokens is not scored here: it counts in ``failed_requests``."""
-    weights = model.make_weights(seed, dm)
+    weights = fam.make_weights(seed, dm)
     out = {"gap": 0.0, "tokens": 0, "controls": dict.fromkeys(controls, 0.0)}
     for i in picks:
         r = reqs[i]
         served = outputs.get(r["rid"])
         if not served or len(served) != r["max_new_tokens"]:
             continue
-        g, low = reference.gaps(weights, dm, r["prompt"], served, controls)
+        g, low = fam.gaps(weights, dm, r["prompt"], served, controls)
         out["gap"] = max(out["gap"], float(g.max()))
         out["tokens"] += len(g)
         for c, x in low.items():

@@ -1,23 +1,25 @@
-"""The benchmark's own view of a configuration: its sizes, its weights made
-from the seed, and the operations and bytes a step needs.
+"""The ``dense`` family: Qwen3 / Llama-style decoders, every layer a GQA
+block with a SwiGLU MLP (``families/__init__.py`` says what a family
+provides).
 
-Nothing here imports the serving program.  Weights are made in the
-published (Hugging Face) layout: ``wq`` is (D, H*hd) with the head dim in
-rotate-half order, and so on.  ``hooks.py`` maps them onto the program's
-layout; ``reference.py`` reads them as they are.
+Weights are made in the published (Hugging Face) layout: ``wq`` is
+(D, H*hd) with the head dim in rotate-half order, and so on.
+``program_params`` maps them onto the program's layout;
+``dense_reference.py`` reads them as they are.  The serving program is
+reached through ``hooks.py`` alone.
 """
 
 from __future__ import annotations
 
-import json
 from dataclasses import dataclass
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-CONFIG_DIR = Path(__file__).resolve().parent / "configs"
-
+import hooks
+from families import root_key
+from families.dense_reference import CONTROLS, gaps  # noqa: F401
 
 @dataclass(frozen=True)
 class Dims:
@@ -57,10 +59,6 @@ class Dims:
         return 2 * 2 * self.kv_heads * self.hd
 
 
-def load_config(name: str) -> dict:
-    return json.loads((CONFIG_DIR / f"{name}.json").read_text())
-
-
 def dims(cfg: dict) -> Dims:
     return Dims(name=cfg["name"], vocab=cfg["vocab_size"],
                 d=cfg["hidden_size"], f=cfg["intermediate_size"],
@@ -76,12 +74,6 @@ def dims(cfg: dict) -> Dims:
 
 
 # -- weights -------------------------------------------------------------------
-
-def root_key(seed: int) -> jax.Array:
-    """A key from any whole number, 64 bits and beyond 32 included."""
-    seed %= 2**64
-    return jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
-
 
 def _norm_scale(key, n):
     return 1.0 + 0.1 * jax.random.normal(key, (n,))
@@ -139,6 +131,54 @@ def make_weights(seed: int, dm: Dims, layer_map=None) -> dict:
     return out
 
 
+# -- the program's view -------------------------------------------------------
+
+def arch_config(cfg: dict, dm: Dims) -> hooks.ArchConfig:
+    return hooks.ArchConfig(
+        name=cfg["name"], family="dense", n_layers=dm.layers, d_model=dm.d,
+        n_heads=dm.heads, n_kv_heads=dm.kv_heads, d_ff=dm.f, vocab=dm.vocab,
+        head_dim=dm.hd, qk_norm=dm.qk_norm, rope_theta=dm.rope_theta,
+        tie_embeddings=dm.tied, source=cfg["source"])
+
+
+def _rope_perm(hd: int) -> np.ndarray:
+    """The program rotates adjacent pairs (2i, 2i+1); the published code
+    rotates (i, i + hd/2).  Reordering the head dim of wq, wk and the qk
+    norms maps one onto the other exactly."""
+    return np.stack([np.arange(hd // 2), np.arange(hd // 2) + hd // 2],
+                    -1).reshape(hd)
+
+
+def _program_layer(dm: Dims):
+    perm = _rope_perm(dm.hd)
+
+    def fn(w):
+        D, H, Hkv, hd = dm.d, dm.heads, dm.kv_heads, dm.hd
+        attn = {"wq": w["wq"].reshape(D, H, hd)[..., perm],
+                "wk": w["wk"].reshape(D, Hkv, hd)[..., perm],
+                "wv": w["wv"].reshape(D, Hkv, hd),
+                "wo": w["wo"].reshape(H, hd, D)}
+        if dm.qk_norm:
+            attn["q_norm"] = w["q_norm"][perm]
+            attn["k_norm"] = w["k_norm"][perm]
+        return {"attn_norm": w["attn_norm"], "attn": attn,
+                "mlp_norm": w["mlp_norm"],
+                "mlp": {k: w[k] for k in ("w_gate", "w_up", "w_down")}}
+    return fn
+
+
+def program_params(seed: int, dm: Dims) -> dict:
+    """The benchmark's weights for this seed, in the program's layout, made
+    on the device in one call."""
+    return make_weights(seed, dm, layer_map=_program_layer(dm))
+
+
+def pool_row_bytes(dm: Dims) -> int:
+    """Bytes of one row of the engine's K/V page pool: K and V, all
+    layers."""
+    return dm.layers * dm.kv_row_bytes
+
+
 # -- operations ----------------------------------------------------------------
 
 def token_flops(dm: Dims, context: int, head: bool) -> int:
@@ -149,4 +189,38 @@ def token_flops(dm: Dims, context: int, head: bool) -> int:
     f += 4 * dm.layers * dm.heads * dm.hd * context
     if head:
         f += 2 * dm.vocab * dm.d
+    return f
+
+
+def step_bytes(dm: Dims, active: int, live: int) -> int:
+    """Least bytes of one decode step: every block's weights, the LM-head
+    matrix, the live K/V rows of every active slot in every layer, and the
+    row each slot appends."""
+    return (dm.layers * dm.layer_weight_bytes + dm.head_bytes
+            + (live + active) * dm.layers * dm.kv_row_bytes)
+
+
+def decode_bytes(dm: Dims, calls, reports) -> int:
+    return sum(step_bytes(dm, c.active, c.live) for c in calls)
+
+
+def walk_bytes(dm: Dims, calls, reports) -> int:
+    """The live K/V rows of every active slot, all layers, K and V."""
+    return sum(c.live for c in calls) * dm.layers * dm.kv_row_bytes
+
+
+def flops(dm: Dims, calls, spans, reports) -> int:
+    """A decoded token: every block's matmuls, attention over its live
+    context, the LM head.  A prefilled token at position p: the matmuls and
+    attention over p + 1 keys; the LM head once per request, for its first
+    token."""
+    f = 0
+    for c in calls:
+        f += c.active * token_flops(dm, 0, head=True)
+        f += 4 * dm.layers * dm.heads * dm.hd * c.live
+    for c0, c1 in spans:
+        n = c1 - c0
+        f += n * token_flops(dm, 0, head=False)
+        f += 4 * dm.layers * dm.heads * dm.hd * (c0 + c1 + 1) * n // 2
+    f += sum(len(r.outputs) for r in reports) * 2 * dm.vocab * dm.d
     return f

@@ -1,5 +1,5 @@
-"""Plain float32 forward pass of a Qwen3 / Llama-style decoder, for the
-check that decides ``correct``.
+"""The ``dense`` family's reference: a plain float32 forward pass of a
+Qwen3 / Llama-style decoder, for the check that decides ``correct``.
 
 It follows the published blocks (Hugging Face ``Qwen3ForCausalLM`` and
 ``LlamaForCausalLM``): RMSNorm with a weight, q/k/v projections, a
@@ -19,12 +19,14 @@ alone: the cache stored in float8, the shortcut nearest to this system.
 from __future__ import annotations
 
 import functools
+from typing import TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from model import Dims
+if TYPE_CHECKING:
+    from families.dense import Dims
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn

@@ -7,4 +7,4 @@ up and drains."""
 def read(ctx):
     if not ctx.decode_calls:
         return None
-    return sum(a for a, _ in ctx.decode_calls) / len(ctx.decode_calls)
+    return sum(c.active for c in ctx.decode_calls) / len(ctx.decode_calls)

@@ -26,8 +26,8 @@ MIX = {"requests": 3, "arrival_every_ticks": 2, "length_seed": 1,
 
 
 def main() -> int:
-    import model
-    cfg = model.load_config(run.cell_of(run.benchmark(), CELL)["config"])
+    import families
+    cfg = families.load_config(run.cell_of(run.benchmark(), CELL)["config"])
     cfg = {**cfg, **SMALL, "engine": {**cfg["engine"], "max_len": 1024}}
     res = run.run_cell(CELL, 1, 0.0, True, config=cfg, mix=MIX,
                        limit=float("inf"))

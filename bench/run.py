@@ -6,8 +6,11 @@
 
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``configs/<config>.json``) and a traffic mix (``traffic/<mix>.json``).
-Set-up makes the weights on the device from the seed, builds one
-``ServingEngine`` and warms it with one whole replay of the cell's trace.
+The configuration names its model family (``families/<family>.py``),
+from which the run takes the sizes, the weights, the program's
+architecture, the reference and the accounting.  Set-up makes the
+weights on the device from the seed, builds one ``ServingEngine`` and
+warms it with one whole replay of the cell's trace.
 The window is back-to-back replays of that trace on the same engine,
 ending with the first replay that finishes at or after ``--seconds``.
 ``--trace 1`` runs the window under the profiler and reports the
@@ -16,8 +19,9 @@ After the window the engine is freed and ``correctness.py`` scores a
 sample of what it served against the float32 reference.
 
 Two options exist for reading the check's other side on the chip, and the
-benchmark's own runs use neither: ``--control fp8`` (or ``fp8_kv``)
-scores the lower-precision reference's picks in place of the served
+benchmark's own runs use neither: ``--control <name>`` (one of the
+family's ``CONTROLS``; dense: ``fp8``, ``fp8_kv``) scores the
+lower-precision reference's picks in place of the served
 tokens, and ``--fault <name>`` plants a fault of ``faults.py`` under the
 timed path.  Either has to come out ``correct: false``.
 
@@ -54,7 +58,9 @@ def parse(argv=None):
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--control", choices=("fp8", "fp8_kv"), default=None)
+    ap.add_argument("--control", default=None,
+                    help="a lower-precision pass of the family's "
+                         "reference (dense: fp8, fp8_kv)")
     ap.add_argument("--fault", default=None)
     return ap.parse_args(argv)
 
@@ -132,16 +138,16 @@ class CompileLog:
 @dataclass
 class Context:
     """What the metric readers see."""
+    family: object                      # the configuration's family module
     dm: object
     peaks: dict
     reports: list
     window_s: float
     setup_s: float
     itl_s: object
-    decode_calls: list                  # (active slots, live rows) per tick
+    decode_calls: list                  # hooks.DecodeCall per tick
     prefill_spans: list                 # (first row, end row) per job/tick
     pool_bytes: int                     # the engine's K/V page pool
-    requests_done: int
     passes: int
     compiles_in_window: int
     memory_peak_bytes: int
@@ -172,8 +178,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     import jax
 
     import correctness
+    import families
     import hooks
-    import model
     import workload as traffic
 
     bench = benchmark()
@@ -181,17 +187,21 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     devs = chips(cell["chips"]) if require_tpu else jax.devices()
     dev = devs[0]
     peaks = peaks or peaks_for(dev.device_kind)
-    cfg = config or model.load_config(cell["config"])
+    cfg = config or families.load_config(cell["config"])
+    fam = families.of(cfg)
+    if control is not None and control not in fam.CONTROLS:
+        raise SystemExit(f"bench: the {cfg['family']!r} family's reference "
+                         f"has no control {control!r}; {fam.CONTROLS}")
     mix = mix or traffic.load_mix(cell["traffic"])
-    dm = model.dims(cfg)
+    dm = fam.dims(cfg)
     log = CompileLog()
 
     t = time.perf_counter()
-    params = hooks.program_params(seed, dm)
+    params = fam.program_params(seed, dm)
     t_weights = time.perf_counter() - t
     reqs = traffic.make_requests(mix, seed, dm.vocab)
     trace = hooks.requests(reqs)
-    eng = hooks.ServingEngine(params, hooks.arch_config(cfg, dm),
+    eng = hooks.ServingEngine(params, fam.arch_config(cfg, dm),
                               hooks.serving_config(cfg["engine"]))
     probe = hooks.EngineProbe(eng)
     if tamper is not None:
@@ -228,12 +238,10 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
 
     interval = eng.cfg.tier.interval
     ctx = Context(
-        dm=dm, peaks=peaks, reports=reports, window_s=window_s,
+        family=fam, dm=dm, peaks=peaks, reports=reports, window_s=window_s,
         setup_s=setup_s, itl_s=probe.itl_samples(),
-        decode_calls=list(zip(probe.active, probe.live)),
-        prefill_spans=list(probe.spans),
-        pool_bytes=hooks.pool_bytes(eng, dm),
-        requests_done=sum(len(r.outputs) for r in reports),
+        decode_calls=probe.calls(), prefill_spans=list(probe.spans),
+        pool_bytes=hooks.pool_bytes(eng, fam.pool_row_bytes(dm)),
         passes=sum(r.steps // interval - r.migration_deferrals
                    for r in reports),
         compiles_in_window=in_window, memory_peak_bytes=int(mem))
@@ -263,7 +271,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     gc.collect()
     picks = correctness.sample(reqs, seed)
     t = time.perf_counter()
-    scored = correctness.served_gap(seed, dm, reqs, outputs, picks,
+    scored = correctness.served_gap(fam, seed, dm, reqs, outputs, picks,
                                     (control,) if control else ())
     gap = scored["controls"][control] if control else scored["gap"]
     t_check = time.perf_counter() - t

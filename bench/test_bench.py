@@ -10,6 +10,10 @@
   where it is made; so does a run with the float8 control scored in the
   program's place;
 * the trace reducer reads the small chip trace kept in ``testdata/``;
+* the ``dense`` family computes, to the bit, what the harness computed
+  before the families held the architecture, and a run reaches the
+  architecture through the configuration's family alone
+  (``testdata/counting.py``);
 * the traffic generator keeps lengths fixed across seeds, and takes a mix
   written as a module;
 * ``BENCHMARK.json`` keeps to the names and keys the harness relies on.
@@ -19,10 +23,14 @@ These tests skip the harness's look for a chip (``require_tpu=False``).
 
 from __future__ import annotations
 
+import importlib
 import json
+import os
 import re
+import subprocess
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -32,9 +40,11 @@ sys.path.insert(0, str(HERE))
 
 import correctness  # noqa: E402
 import faults  # noqa: E402
-import model  # noqa: E402
+import families  # noqa: E402
+import hooks  # noqa: E402
 import run  # noqa: E402
 import workload  # noqa: E402
+from families import dense, dense_reference  # noqa: E402
 
 TINY = {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
@@ -51,7 +61,7 @@ CELLS = ["qwen3-1.7b.doc_qa", "yi-9b.chat"]
 
 def tiny(cell: str, **engine):
     c = run.cell_of(run.benchmark(), cell)
-    cfg = model.load_config(c["config"])
+    cfg = families.load_config(c["config"])
     cfg = {**cfg, **TINY, "engine": {**cfg["engine"], "max_len": 512,
                                      **engine}}
     mix = dict(TINY_MIX)
@@ -72,7 +82,7 @@ def test_reference_agrees_and_control_does_not(cell):
     """The program's served tokens sit within bf16 rounding of the
     reference's best; the float8 control's picks do not."""
     cfg, mix = tiny(cell)
-    dm = model.dims(cfg)
+    dm = dense.dims(cfg)
     res = run_tiny(cell)
     assert res["correct"], res["checks"]
     gap = res["checks"]["served_logit_gap"]["value"]
@@ -81,24 +91,23 @@ def test_reference_agrees_and_control_does_not(cell):
     # greedy tokens stand in for what was served
     outputs = {r["rid"]: _greedy(dm, 2**31 + 17, r) for r in reqs}
     picks = correctness.sample(reqs, 2**31 + 17)
-    got = correctness.served_gap(2**31 + 17, dm, reqs, outputs, picks,
-                                 ("fp8",))
+    got = correctness.served_gap(dense, 2**31 + 17, dm, reqs, outputs,
+                                 picks, ("fp8",))
     assert got["gap"] == 0.0
     assert got["controls"]["fp8"] > TINY_LIMIT > gap, (got, gap)
 
 
 def _greedy(dm, seed, r):
     import jax.numpy as jnp
-    import reference
-    w = model.make_weights(seed, dm)
+    ref = dense_reference
+    w = dense.make_weights(seed, dm)
     seq = list(r["prompt"])
     for _ in range(r["max_new_tokens"]):
         tokens = jnp.asarray(np.asarray(seq, np.int32))
-        pad = -(-len(seq) // reference.PAD) * reference.PAD
+        pad = -(-len(seq) // ref.PAD) * ref.PAD
         tokens = jnp.pad(tokens, (0, pad - len(seq)))
-        idx = jnp.full((reference.HEAD_PAD,), len(seq) - 1, jnp.int32)
-        seq.append(int(jnp.argmax(reference.logits_at(w, tokens, idx,
-                                                      dm)[0])))
+        idx = jnp.full((ref.HEAD_PAD,), len(seq) - 1, jnp.int32)
+        seq.append(int(jnp.argmax(ref.logits_at(w, tokens, idx, dm)[0])))
     return seq[len(r["prompt"]):]
 
 
@@ -182,6 +191,158 @@ def test_longest_answer_first_keeps_the_drawn_lengths():
     assert served == sorted(served, reverse=True)
     assert sorted(served) == sorted(r["max_new_tokens"] for r in b)
     assert [len(r["prompt"]) for r in a] == [len(r["prompt"]) for r in b]
+
+
+# Integers from the formulas as they stood before the families held the
+# architecture (commit ecf5bf2: ``model.Dims`` and ``model.token_flops``,
+# ``hooks.pool_bytes``, ``metrics/decode_roofline.step_bytes`` and the
+# sums in ``metrics/step_mfu.py`` and ``metrics/walk_roofline.py``), run
+# once at these (active slots, live rows) pairs.  ``token_flops`` is at
+# (context 0, head), (0, no head), (3072, head); ``pool_bytes`` at 3,072
+# pages of 16 rows; ``window_flops`` is step_mfu's sum over the three pairs
+# as decode calls, prefill spans (0, 512) and (3072, 3200), and 5 requests.
+PAIRS = [(1, 1), (7, 23113), (8, 32768)]
+SPANS = [(0, 512), (3072, 3200)]
+GOLDEN = {
+    "qwen3-1.7b": {
+        "dims": "Dims(name='qwen3-1.7b', vocab=151936, d=2048, f=6144, "
+                "layers=28, heads=16, kv_heads=8, hd=128, qk_norm=True, "
+                "tied=True, rope_theta=1000000.0, eps=1e-06)",
+        "step_bytes": [3441375232, 6092732416, 7200159744],
+        "walk_bytes": [114688, 2650783744, 3758096384],
+        "token_flops": [3440902144, 2818572288, 4145545216],
+        "pool_bytes": 5637144576,
+        "window_flops": 1997081870336},
+    "yi-9b": {
+        "dims": "Dims(name='yi-9b', vocab=64000, d=4096, f=11008, "
+                "layers=24, heads=32, kv_heads=4, hd=128, qk_norm=False, "
+                "tied=False, rope_theta=5000000.0, eps=1e-06)",
+        "step_bytes": [8829501440, 9965797376, 10440409088],
+        "walk_bytes": [49152, 1136050176, 1610612736],
+        "token_flops": [8829009920, 8304721920, 10036969472],
+        "pool_bytes": 2415919104,
+        "window_flops": 5690386808832},
+}
+# ``testdata/reference_bits.py``'s digests of the same code's output at
+# TINY widths, seed 2**31 + 17 (the weights in both layouts; the
+# reference's logits, plain and under each control; ``gaps``), as the code
+# before the families gave them on one core.
+GOLDEN_BITS = {
+    "qwen3-1.7b": {
+        "weights": "15a9c1094c817f4b", "program_weights": "d78b46333b90359a",
+        "logits": "0a294807ce31d9bd", "logits_fp8": "e1fb4693e9308ddd",
+        "logits_fp8_kv": "dc2cf693adf4e932", "gaps": "44717a603a26c836"},
+    "yi-9b": {
+        "weights": "6309c600cf10fb6a", "program_weights": "50de56165f0900f4",
+        "logits": "0db970d199c9c8a4", "logits_fp8": "e3044052b49b6707",
+        "logits_fp8_kv": "53eed065b1a78b31", "gaps": "2cfdc79a290ec556"},
+}
+
+
+def _call(active, live):
+    """A decode call with ``live`` rows spread over ``active`` slots."""
+    rows = [live // active] * active
+    rows[0] += live - sum(rows)
+    return hooks.DecodeCall(active, live, tuple(rows))
+
+
+@pytest.mark.parametrize("name", sorted(GOLDEN))
+def test_dense_accounting_matches_the_formulas_before_families(name):
+    g = GOLDEN[name]
+    dm = dense.dims(families.load_config(name))
+    assert repr(dm) == g["dims"]
+    assert [dense.decode_bytes(dm, [_call(a, l)], []) for a, l in PAIRS] \
+        == g["step_bytes"]
+    assert [dense.walk_bytes(dm, [_call(a, l)], []) for a, l in PAIRS] \
+        == g["walk_bytes"]
+    assert [dense.token_flops(dm, c, h) for c, h in
+            ((0, True), (0, False), (3072, True))] == g["token_flops"]
+    eng = SimpleNamespace(pool_pages=3072,
+                          cfg=SimpleNamespace(tier=SimpleNamespace(page=16)))
+    assert hooks.pool_bytes(eng, dense.pool_row_bytes(dm)) == g["pool_bytes"]
+    reports = [SimpleNamespace(outputs=dict.fromkeys(range(3))),
+               SimpleNamespace(outputs=dict.fromkeys(range(2)))]
+    calls = [_call(a, l) for a, l in PAIRS]
+    assert dense.flops(dm, calls, SPANS, reports) == g["window_flops"]
+    assert dense.decode_bytes(dm, calls, reports) == sum(g["step_bytes"])
+    assert dense.walk_bytes(dm, calls, reports) == sum(g["walk_bytes"])
+
+
+@pytest.mark.parametrize("name", sorted(GOLDEN_BITS))
+def test_dense_weights_and_reference_are_bit_identical(name):
+    out = subprocess.run(
+        [sys.executable, str(HERE / "testdata" / "reference_bits.py"), name,
+         str(2**31 + 17), json.dumps(TINY)],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+        text=True, check=True)
+    assert json.loads(out.stdout.splitlines()[-1]) == GOLDEN_BITS[name]
+
+
+def test_family_seam_is_the_only_route(monkeypatch):
+    """A run of a configuration that names the test-only ``counting``
+    family (``testdata/counting.py``, ``dense`` with every call counted)
+    makes every weight, reference and accounting call through it, and
+    reads exactly what the ``dense`` family's run reads.  The traced
+    window's reduction reads the small chip trace, so that the three
+    accounting readers have device time to divide by."""
+    import jax
+    import trace_reduce
+    monkeypatch.setattr(families, "__path__",
+                        [*families.__path__, str(HERE / "testdata")])
+    monkeypatch.setattr(trace_reduce, "newest_xplane",
+                        lambda _: HERE / "testdata" / "small.xplane.pb")
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    cell = CELLS[0]
+    cfg, mix = tiny(cell)
+
+    def once(family):
+        return run.run_cell(cell, 2**31 + 17, 0.0, True, require_tpu=False,
+                            config={**cfg, "family": family}, mix=mix,
+                            peaks=PEAKS, limit=TINY_LIMIT)
+
+    want = once("dense")
+    counting = importlib.import_module("families.counting")
+    counting.CALLS.clear()
+    got = once("counting")
+    assert set(counting.CALLS) == {k for k in families.PROVIDES
+                                   if callable(getattr(dense, k))}
+    for m in ("decode_roofline", "step_mfu", "walk_roofline"):
+        assert got["metrics"][m]["value"] > 0
+    assert got == want
+    # and no file of the harness names a family of its own accord
+    names_one = re.compile(r"families\.dense|import dense|['\"]dense['\"]")
+    harness = [HERE / f for f in ("run.py", "correctness.py", "calibrate.py",
+                                  "record_trace.py", "hooks.py")]
+    for f in harness + sorted((HERE / "metrics").glob("*.py")):
+        assert not names_one.search(f.read_text()), f
+
+
+def test_a_missing_family_stops_the_run():
+    cfg, mix = tiny(CELLS[0])
+    for bad, said in (({**cfg, "family": "no_such"}, "'no_such'"),
+                      ({k: v for k, v in cfg.items() if k != "family"},
+                       "names no family")):
+        with pytest.raises(SystemExit, match=said):
+            run.run_cell(CELLS[0], 1, 0.0, False, require_tpu=False,
+                         config=bad, mix=mix, peaks=PEAKS, limit=TINY_LIMIT)
+
+
+def test_probe_keeps_each_slots_rows():
+    """``EngineProbe`` notes each active slot's live rows beside their sum,
+    from the engine's host mirror of ``pos``."""
+    eng = SimpleNamespace(run=None, _decode=lambda *a: a,
+                          _advance_prefills=None,
+                          slots=[None, "a", None, "b", "c"],
+                          pos=np.array([9, 4, 0, 17, 2]))
+    probe = hooks.EngineProbe(eng)
+    probe.decode(1)
+    eng.slots[1] = None
+    eng.pos = eng.pos + 1
+    probe.decode(2)
+    assert probe.calls() == [hooks.DecodeCall(3, 26, (5, 18, 3)),
+                             hooks.DecodeCall(2, 23, (19, 4))]
+    assert probe.live == [26, 23] and probe.active == [3, 2]
 
 
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
