@@ -8,7 +8,7 @@ through the isolation transistor: cost is paid per access, never per bit of
 the segment (PAPER.md §3).  This kernel applies that economics to the
 gather path itself:
 
-  grid (B,); per slot the kernel, for ALL KV heads at once,
+  grid (B,); per slot the kernel, for ALL KV heads in one grid step,
     1. attends the shared NEAR buffer (VMEM-resident: its block index never
        changes, so it is fetched once per call, not once per slot) under
        per-(slot, near-slot) live counts — the global near tier serves
@@ -31,11 +31,22 @@ gather path itself:
 
 Layout (docs/design.md §2e): the pool keeps its `(P, page, Hkv, hd)` layout,
 so the `(Hkv, hd)` minor dims of every block are whole arrays and satisfy the
-TPU's (8, 128) tiling rule at any head count.  Scores and the p·v update are
-VPU multiply + reduce over `hd` (lanes) and `page` (leading dim) in f32; the
-per-slot ints (walk list, live counts, near live counts) ride in SMEM via
-scalar prefetch.  Queries enter as `(B, g, Hkv, hd)` so each GQA group is one
-`(Hkv, hd)` panel; m/l leave lane-replicated as `(B, g, Hkv, 128)`.
+TPU's (8, 128) tiling rule at any head count; the per-slot ints (walk list,
+live counts, near live counts) ride in SMEM via scalar prefetch.  Queries
+enter as `(B, Hkv, g, hd)`, so KV head h's g queries are one `(g, hd)` panel.
+Each block is computed head by head on the MXU: a strided read of head h's
+`(rows, hd)` K and V rows from the block buffer (bf16 as 32-bit words,
+`_head_pair`), the scores
+`q_h · K_hᵀ` as a `(g, rows)` f32 panel with the block's rows on lanes, and
+`p · V_h` into a `(g, hd)` f32 accumulator; the softmax state is one
+(m, l, acc) row per (head, group) and m/l leave lane-replicated as
+`(B, Hkv, g, 128)`.  Operands enter the MXU in their stored dtype with f32
+accumulation (bf16 x bf16 products are exact); the `hd ** -0.5` scale is
+applied to the f32 scores; f32 operands use HIGHEST; the f32 `p` is never
+rounded to bf16 alone (`_p_dot_v`).  No dot spans two heads: an all-heads
+dot would do Hkv x the exp work, and its f32 sums would group differently
+as Hkv changes, so a KV-head-sharded call would no longer match one device
+bit for bit.
 
 Returns *unnormalized* `(out, m, l)` online-softmax stats, the same
 contract as `kernels.tiered_attention`, so callers can LSE-merge with other
@@ -115,6 +126,58 @@ def paged_attention_stats(q: jax.Array, pool_k: jax.Array,
                    *walk)
 
 
+def _scores(q, k):
+    """(g, hd) queries against a block's (rows, hd) keys of one KV head:
+    (g, rows) f32 on the MXU, rows on lanes.  bf16 x bf16 products are
+    exact in f32; any other pair is taken in f32 at HIGHEST."""
+    nt = (((1,), (1,)), ((), ()))
+    if q.dtype == k.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(q, k, nt,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(q.astype(jnp.float32), k.astype(jnp.float32),
+                               nt, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _p_dot_v(p, v):
+    """(g, rows) f32 probabilities times a block's (rows, hd) values of one
+    KV head, on the MXU, into f32.  ``p`` is never rounded to bf16 alone:
+    bf16 values take p as a hi/lo pair of bf16 panels (two exact passes,
+    p_hi + p_lo carries 16 of p's 24 bits); f32 values a HIGHEST dot."""
+    if v.dtype == jnp.bfloat16:
+        hi = p.astype(jnp.bfloat16)
+        lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        return (jnp.dot(hi, v, preferred_element_type=jnp.float32)
+                + jnp.dot(lo, v, preferred_element_type=jnp.float32))
+    return jnp.dot(p, v.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _head_pair(ref, start, n, s, keep=None):
+    """KV heads 2s and 2s+1: their ``(n, hd)`` bf16 rows ``start`` to
+    ``start + n`` of a bf16 ``(N, Hkv, hd)`` VMEM ref.  A row packs two
+    heads to a 32-bit word (head 2s in the low half of word s, 2s+1 in the
+    high half) and a bf16 panel packs two rows to a word (row 2i low, 2i+1
+    high), so each head is two sublane-strided 32-bit loads (the even and
+    the odd rows) and a repacking of their halves: exact, with no bf16
+    unpacking.  ``keep``: (even, odd) ``(n/2, hd)`` masks of the rows to
+    keep; the others read as zero."""
+    N, Hkv, hd = ref.shape
+    half = Hkv // 2
+    words = ref.bitcast(jnp.uint32).reshape(N * half, hd)
+
+    def rows(parity):
+        w = words[pl.ds((start + parity) * half + s, n // 2,
+                        stride=2 * half), :]
+        return w if keep is None else jnp.where(keep[parity], w, 0)
+
+    even, odd = rows(0), rows(1)
+    low, high = jnp.uint32(0xFFFF), jnp.uint32(0xFFFF0000)
+    return (pltpu.bitcast((even & low) | (odd << 16), jnp.bfloat16),
+            pltpu.bitcast((even >> 16) | (odd & high), jnp.bfloat16))
+
+
 def _paged_attention_kernel(layer_ref, walk_pid_ref, walk_live_ref,
                             walk_len_ref, near_live_ref, q_ref, nk_ref,
                             nv_ref, pool_k_ref, pool_v_ref, o_ref, m_ref,
@@ -125,43 +188,61 @@ def _paged_attention_kernel(layer_ref, walk_pid_ref, walk_live_ref,
     if stacked:                         # (L, P, page, Hkv, hd) pools
         pool_k_ref = pool_k_ref.at[layer_ref[0]]
         pool_v_ref = pool_v_ref.at[layer_ref[0]]
-    _, g, Hkv, hd = q_ref.shape
-    qs = [q_ref[0, j].astype(jnp.float32) * scale for j in range(g)]
-    row = jax.lax.broadcasted_iota(jnp.int32, (page, Hkv, 1), 0)
+    _, Hkv, g, hd = q_ref.shape
+    qs = [q_ref[0, h] for h in range(Hkv)]      # (g, hd): head h's queries
+    packed = (kbuf.dtype == vbuf.dtype == jnp.bfloat16 and Hkv % 2 == 0
+              and page % 2 == 0)                # two heads, two rows a word
 
-    def update(carry, load, lives):
-        """One online-softmax update over ``len(lives)`` pages for every
-        (group, head): one running-max rescale per block, not per page.
-        ``load(p)`` gives page p's (page, Hkv, hd) K and V rows; rows at or
-        past ``lives[p]`` are dead and may hold stale or uninitialised
-        bytes, so they are kept out of the scores AND zeroed in V
-        (0 * NaN is NaN)."""
-        alive = [row < live for live in lives]
-        kvs = [load(p) for p in range(len(lives))]
-        ks = [k.astype(jnp.float32) for k, _ in kvs]
-        vs = [jnp.where(a, v.astype(jnp.float32), 0.0)
-              for a, (_, v) in zip(alive, kvs)]
-        new = []
-        for j in range(g):
-            acc, m, l = carry[j]
-            s = [jnp.where(a, jnp.sum(k * qs[j][None], axis=-1,
-                                      keepdims=True), NEG_INF)
-                 for a, k in zip(alive, ks)]            # (page, Hkv, 1) each
-            m_new = m
-            for sp in s:
-                m_new = jnp.maximum(m_new, jnp.max(sp, axis=0))  # (Hkv, 1)
+    def update(carry, kref, vref, start, lives):
+        """One online-softmax update over the ``len(lives)`` pages that
+        start at row ``start`` of ``kref``/``vref`` ``(N, Hkv, hd)``, head
+        by head: one running-max rescale per block, not per page.  Rows at
+        or past their page's ``lives[p]`` are dead and may hold stale or
+        uninitialised bytes, so they are kept out of the scores AND zeroed
+        in V (0 * NaN is NaN)."""
+        rows = len(lives) * page
+
+        def alive(shape, axis):         # the block's live rows along ``axis``
+            r = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+            end = jnp.zeros(shape, jnp.int32)
+            for p, live in enumerate(lives):
+                end = jnp.where(r >= p * page, p * page + live, end)
+            return r < end
+
+        on_lanes = alive((1, rows), 1)
+
+        def step(h, k, v):
+            acc, m, l = carry[h]
+            s = jnp.where(on_lanes, _scores(qs[h], k) * scale, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
-            acc, l = acc * alpha, l * alpha
-            for a, sp, v in zip(alive, s, vs):
-                p = jnp.where(a, jnp.exp(sp - m_new[None]), 0.0)
-                acc = acc + jnp.sum(p * v, axis=0)
-                l = l + jnp.sum(p, axis=0)
-            new.append((acc, m_new, l))
+            p = jnp.where(on_lanes, jnp.exp(s - m_new), 0.0)   # (g, rows)
+            return (acc * alpha + _p_dot_v(p, v), m_new,
+                    l * alpha + jnp.sum(p, axis=1, keepdims=True))
+
+        new = []
+        if packed:
+            # rows 2i + parity of the block against their page's live count
+            i = jax.lax.broadcasted_iota(jnp.int32, (rows // 2, hd), 0)
+            ends = jnp.concatenate([jnp.full((page // 2, hd), live)
+                                    for live in lives])
+            keep = [2 * (i % (page // 2)) + parity < ends
+                    for parity in (0, 1)]
+            for s in range(Hkv // 2):
+                ks = _head_pair(kref, start, rows, s)
+                vs = _head_pair(vref, start, rows, s, keep)
+                new += [step(2 * s + j, ks[j], vs[j]) for j in (0, 1)]
+            return tuple(new)
+        on_sublanes = alive((rows, 1), 0)
+        for h in range(Hkv):
+            block = (pl.ds(start, rows), h, slice(None))
+            new.append(step(h, kref[block],
+                            jnp.where(on_sublanes, vref[block], 0)))
         return tuple(new)
 
-    carry = tuple((jnp.zeros((Hkv, hd), jnp.float32),
-                   jnp.full((Hkv, 1), NEG_INF, jnp.float32),
-                   jnp.zeros((Hkv, 1), jnp.float32)) for _ in range(g))
+    carry = tuple((jnp.zeros((g, hd), jnp.float32),
+                   jnp.full((g, 1), NEG_INF, jnp.float32),
+                   jnp.zeros((g, 1), jnp.float32)) for _ in range(Hkv))
 
     # -- far copies: the slot's live, non-promoted pages, kb pages a block
     #    in two block buffers; block 0 flies while the near pass computes --
@@ -190,11 +271,9 @@ def _paged_attention_kernel(layer_ref, walk_pid_ref, walk_live_ref,
     kn = math.gcd(n_near, kb)           # divides C: no block runs past it
 
     def near_body(i, carry):
-        def load(p):
-            rows = pl.ds((i * kn + p) * page, page)
-            return nk_ref[rows], nv_ref[rows]
-        return update(carry, load, [near_live_ref[b * n_near + i * kn + p]
-                                    for p in range(kn)])
+        return update(carry, nk_ref, nv_ref, i * kn * page,
+                      [near_live_ref[b * n_near + i * kn + p]
+                       for p in range(kn)])
 
     carry = jax.lax.fori_loop(0, n_near // kn, near_body, carry)
 
@@ -208,19 +287,15 @@ def _paged_attention_kernel(layer_ref, walk_pid_ref, walk_live_ref,
             e = i * kb + p                  # past W only when past n
             lives.append(jnp.where(
                 e < n, walk_live_ref[b * W + jnp.minimum(e, W - 1)], 0))
-
-        def load(p):
-            rows = pl.ds(p * page, page)
-            return kbuf[slot, rows], vbuf[slot, rows]
-        return update(carry, load, lives)
+        return update(carry, kbuf.at[slot], vbuf.at[slot], 0, lives)
 
     carry = jax.lax.fori_loop(0, pl.cdiv(n, kb), far_block, carry)
 
-    for j in range(g):
-        acc, m, l = carry[j]
-        o_ref[0, j] = acc
-        m_ref[0, j] = jnp.broadcast_to(m, (Hkv, LANES))
-        l_ref[0, j] = jnp.broadcast_to(l, (Hkv, LANES))
+    for h in range(Hkv):
+        acc, m, l = carry[h]
+        o_ref[0, h] = acc
+        m_ref[0, h] = jnp.broadcast_to(m, (g, LANES))
+        l_ref[0, h] = jnp.broadcast_to(l, (g, LANES))
 
 
 def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
@@ -252,8 +327,8 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     n_near = near_k.shape[0] // page
     W = walk_pid.shape[1]
     flat = lambda a: jnp.asarray(a, jnp.int32).reshape(-1)
-    # (B, g, Hkv, hd): each GQA group is one (Hkv, hd) panel
-    qg = q.reshape(B, Hkv, g, hd).transpose(0, 2, 1, 3)
+    # (B, Hkv, g, hd): KV head h's queries are one (g, hd) panel
+    qh = q.reshape(B, Hkv, g, hd)
 
     kb = max(1, min(W, BLOCK_ROWS // page))      # far pages per block
     kernel = functools.partial(_paged_attention_kernel, page=page,
@@ -267,16 +342,16 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
             num_scalar_prefetch=5,
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, g, Hkv, hd), per_slot),
+                pl.BlockSpec((1, Hkv, g, hd), per_slot),
                 pl.BlockSpec((n_near * page, Hkv, hd), resident),
                 pl.BlockSpec((n_near * page, Hkv, hd), resident),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
-                pl.BlockSpec((1, g, Hkv, hd), per_slot),
-                pl.BlockSpec((1, g, Hkv, LANES), per_slot),
-                pl.BlockSpec((1, g, Hkv, LANES), per_slot),
+                pl.BlockSpec((1, Hkv, g, hd), per_slot),
+                pl.BlockSpec((1, Hkv, g, LANES), per_slot),
+                pl.BlockSpec((1, Hkv, g, LANES), per_slot),
             ],
             scratch_shapes=[
                 pltpu.VMEM((2, kb * page, Hkv, hd), pool_k.dtype),
@@ -284,15 +359,13 @@ def paged_attention(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                 pltpu.SemaphoreType.DMA((2, 2, kb)),   # (K|V, buffer, page)
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((B, g, Hkv, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, g, Hkv, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B, g, Hkv, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, g, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, g, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, g, LANES), jnp.float32),
         ],
         name="paged_walk",
         interpret=interpret,
     )(flat(0 if layer is None else layer), flat(walk_pid), flat(walk_live),
-      flat(walk_len), flat(near_live), qg, near_k, near_v, pool_k, pool_v)
-    heads = lambda a: a.transpose(0, 2, 1, *range(3, a.ndim))
-    return (heads(out).reshape(B, H, hd),
-            heads(m[..., 0]).reshape(B, H),
-            heads(l[..., 0]).reshape(B, H))
+      flat(walk_len), flat(near_live), qh, near_k, near_v, pool_k, pool_v)
+    return (out.reshape(B, H, hd), m[..., 0].reshape(B, H),
+            l[..., 0].reshape(B, H))

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 try:                                   # optional fast path: real hypothesis
@@ -14,7 +15,7 @@ except ImportError:                    # seeded fallback harness (tests/_prop)
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_fwd
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import _head_pair, paged_attention
 from repro.kernels.paged_gather import paged_gather
 from repro.kernels.ssd_scan import ssd_chunk_scan
 from repro.kernels.tiered_attention import near_decode_attention
@@ -167,6 +168,8 @@ class TestPagedAttention:
                      id="block-edges-kb4"),
         pytest.param(6, 32, 4, 128, 16, 21, 3, 128, (0, 1, 7, 8, 9, 19),
                      id="block-edges-kb8-yi-gqa"),          # Hkv 4, g 8
+        pytest.param(6, 16, 8, 128, 16, 21, 3, 128, (0, 1, 7, 8, 9, 19),
+                     id="block-edges-kb8-qwen-gqa"),        # Hkv 8, g 2
     ])
     def test_against_ref(self, dtype, B, H, Hkv, hd, page, n_pages, C, P,
                          lens):
@@ -258,6 +261,7 @@ class TestPagedAttention:
             q, pool_k, pool_v, near_k, near_v, *args,
             interpret=pltpu.InterpretParams(uninitialized_memory="nan",
                                             detect_races=True))
+        jax.block_until_ready(got)      # the race record is written as it runs
         assert not interpret_pallas_call.races.races_found
         for a in got:
             assert np.isfinite(np.asarray(a)).all()
@@ -268,6 +272,66 @@ class TestPagedAttention:
         w = np.asarray(want[0]) / np.maximum(np.asarray(want[2])[..., None],
                                              1e-30)
         np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("Hkv", [2, 4, 8])
+    def test_head_pair_reads_each_head_exactly(self, Hkv, masked):
+        """The packed bf16 read returns head h's rows bit for bit, rows
+        start.. of the ref, and zero in exactly the rows its masks drop.
+        Every sign, mantissa and low exponent bit is random (the top
+        exponent bit is cleared: the CPU quiets signalling NaNs)."""
+        N, start, n, hd = 64, 16, 32, 128
+        ks = jax.random.split(jax.random.key(18), 2)
+        x = jax.lax.bitcast_convert_type(
+            jax.random.bits(ks[0], (N, Hkv, hd), jnp.uint16)
+            & jnp.uint16(0xBFFF), jnp.bfloat16)
+        alive = jax.random.bernoulli(ks[1], 0.7, (n,))
+
+        def kernel(x_ref, alive_ref, o_ref):
+            keep = None
+            if masked:
+                a = jnp.broadcast_to(alive_ref[...], (n // 2, 2, hd))
+                keep = (a[:, 0] != 0, a[:, 1] != 0)
+            for s in range(Hkv // 2):
+                o_ref[2 * s], o_ref[2 * s + 1] = _head_pair(x_ref, start, n,
+                                                            s, keep)
+
+        got = pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct((Hkv, n, hd),
+                                                   jnp.bfloat16),
+            interpret=True)(x, alive.astype(jnp.int32).reshape(n // 2, 2, 1))
+        want = jnp.swapaxes(x[start:start + n], 0, 1)
+        if masked:
+            want = jnp.where(alive[None, :, None], want, jnp.bfloat16(0))
+        bits = lambda a: np.asarray(jax.lax.bitcast_convert_type(a,
+                                                                 jnp.uint16))
+        np.testing.assert_array_equal(bits(got), bits(want))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("H,Hkv", [(16, 8), (32, 4)],
+                             ids=["qwen-gqa", "yi-gqa"])
+    def test_head_slice_is_bit_identical(self, H, Hkv, dtype):
+        """No arithmetic crosses KV heads: the kernel run on the first
+        Hkv/2 KV heads (and their query heads) returns exactly that slice
+        of the full call's out, m and l — what a KV-head-sharded mesh
+        relies on to gather stats bit-identical to one device."""
+        B, hd, page, n_pages, C, P = 4, 128, 16, 11, 2, 48
+        ks = jax.random.split(jax.random.key(14), 6)
+        q = _rand(ks[0], (B, H, hd), dtype)
+        pool_k = _rand(ks[1], (P, page, Hkv, hd), dtype)
+        pool_v = _rand(ks[2], (P, page, Hkv, hd), dtype)
+        near_k = _rand(ks[3], (C * page, Hkv, hd), dtype)
+        near_v = _rand(ks[4], (C * page, Hkv, hd), dtype)
+        walk = _walk_meta(ks[5], B, P, page, n_pages, C, (0, 3, 8, 11))
+        full = paged_attention(q, pool_k, pool_v, near_k, near_v, *walk,
+                               interpret=True)
+        hl = Hkv // 2
+        part = paged_attention(q[:, :H // 2], pool_k[..., :hl, :],
+                               pool_v[..., :hl, :], near_k[:, :hl],
+                               near_v[:, :hl], *walk, interpret=True)
+        for got, want in zip(part, full):
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want)[:, :H // 2])
 
     @given(seed=st.integers(0, 99))
     @settings(max_examples=10, deadline=None)
